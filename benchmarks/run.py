@@ -49,7 +49,10 @@ def main() -> None:
 
     import jax
 
+    from repro import compile_cache
     from repro.core import schedulers
+
+    compile_cache.configure()
 
     bench: dict = {
         "meta": {

@@ -28,16 +28,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from . import bitvec, overlay, schedulers
 from .partition import GraphMemory
 
-# jax >= 0.6 exposes shard_map at the top level (check_vma kwarg); older
-# releases ship it under jax.experimental (check_rep kwarg).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SM_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_KW = {"check_rep": False}
-
-
 def _shard_shift(axis_name: str, axis_idx: int, n: int):
     """Torus shift by +1 along array axis ``axis_idx`` where that axis is
     sharded ``n``-way over mesh axis ``axis_name``: local roll + ppermute of
@@ -66,13 +56,17 @@ def _mk_chunk(gl, cfg, K, sched, mk_cycle, all_reduce, nsx, nsy, *, batched):
     """Per-shard chunk step honoring ``cfg.engine``.
 
     The megakernel chunk (one fused ``pallas_call`` per K cycles) cannot
-    contain the cross-shard ppermute a sharded torus shift needs, so it only
-    engages when both mesh axes are size 1 — the shifts are then pure local
-    rolls and the shard-local grid IS the global grid (x0 = y0 = 0). Any
-    real multi-shard mesh silently falls back to the jnp chunk, whose
-    once-per-chunk psum/pmin already amortizes the collectives
-    (docs/megakernel.md, "Fallback semantics")."""
-    if cfg.engine == "megakernel" and nsx == 1 and nsy == 1:
+    contain the cross-shard ppermute a sharded torus shift needs, so it runs
+    only when both mesh axes are size 1 — the shifts are then pure local
+    rolls and the shard-local grid IS the global grid (x0 = y0 = 0). On a
+    real multi-shard mesh it raises rather than run some other engine than
+    the one asked for (docs/megakernel.md, "Mesh semantics")."""
+    if cfg.engine == "megakernel":
+        if nsx != 1 or nsy != 1:
+            raise ValueError(
+                f"engine='megakernel' cannot run on a multi-shard mesh "
+                f"({nsx}x{nsy} shards): the fused kernel cannot hold the "
+                f"cross-shard torus exchange; use engine='jnp' or 'select'")
         from ..kernels import megakernel
 
         return megakernel.make_mega_chunk_fn(
@@ -139,10 +133,10 @@ def _simulate_sharded(gm: GraphMemory, mesh: Mesh,
     nsx = mesh.shape[axis_x]
     nsy = mesh.shape[axis_y]
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(jax.tree.map(spec_for, dict(g)),),
                        out_specs=P(),
-                       **_SM_KW)
+                       check_vma=False)
     def run(gl):
         state = overlay.init_state(gl, cfg, scheduler=sched)
         nx_loc = gl["opcode"].shape[0]
@@ -280,10 +274,10 @@ def _simulate_batch_sharded(gm: GraphMemory, mesh: Mesh,
     nsx = mesh.shape[axis_x]
     nsy = mesh.shape[axis_y]
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(jax.tree.map(spec_for, dict(g)), P(), P(), P()),
                        out_specs=P(),
-                       **_SM_KW)
+                       check_vma=False)
     def run(gl, policy_ids, sel_lats, max_cycs):
         nx_loc = gl["opcode"].shape[0]
         ny_loc = gl["opcode"].shape[1]

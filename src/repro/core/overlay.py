@@ -115,9 +115,9 @@ class OverlayConfig:
       * ``"megakernel"`` — the whole ``check_every``-cycle chunk fused into
         ONE ``pallas_call`` (:mod:`repro.kernels.megakernel`): select +
         Hoplite route + fused eject + termination counter with state
-        carried across cycles in kernel refs. Sharded engines fall back to
-        ``"jnp"`` chunks whenever a mesh axis is >1 (collectives cannot
-        live inside a kernel — see docs/megakernel.md).
+        carried across cycles in kernel refs. Sharded engines raise
+        ``ValueError`` for it whenever a mesh axis is >1 (collectives
+        cannot live inside a kernel — see docs/megakernel.md).
 
     On non-TPU backends the Pallas engines run in interpret mode.
 

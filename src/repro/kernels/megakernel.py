@@ -38,17 +38,17 @@ chunked path stays the reference oracle; ``tests/test_megakernel.py`` pins
 every policy x chunk depth x engine combination bit-for-bit, and the BENCH
 ``megakernel`` section gates the fig1-family cycle counts.
 
-Fallback semantics
-------------------
+Mesh and backend semantics
+--------------------------
 The kernel cannot contain cross-shard collectives, so the sharded engines
-(:mod:`repro.core.distributed`) route ``engine="megakernel"`` through the
-fused chunk only when both mesh axes are size 1 (torus shifts are then pure
-local rolls); real multi-shard meshes silently fall back to the jnp chunk,
-whose per-chunk psum/pmin already amortizes the collectives. On non-TPU
-backends the kernel executes in Pallas interpret mode (the validated CI
-configuration); bool-dtype refs and the dynamic per-PE gathers inside the
-cycle body are interpret/TPU-Mosaic-maturity territory, which is why the
-jnp path remains the default engine.
+(:mod:`repro.core.distributed`) run ``engine="megakernel"`` only when both
+mesh axes are size 1 (torus shifts are then pure local rolls); on a real
+multi-shard mesh they raise ``ValueError``. On non-TPU backends the kernel
+executes in Pallas interpret mode (the validated CI configuration). The TPU
+compiler refuses it: the dynamic per-PE ``[nx, ny, L]`` gathers of the
+cycle body give ``NotImplementedError: Only 2D gather is supported``
+(pinned in ``tests/test_tpu_compile.py``), which is why the jnp path
+remains the default engine.
 """
 from __future__ import annotations
 

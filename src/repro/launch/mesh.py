@@ -12,12 +12,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 takes axis_types; 0.4.x has neither the kwarg nor AxisType
-    # (Auto is the 0.4.x behavior, so omitting it is equivalent).
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -20,6 +20,8 @@ Costs, deltas, and accept decisions are all int64 arithmetic on int32 tables
 PRNG, so for a fixed :class:`repro.place.spec.AnnealConfig` the result is
 bit-identical across runs, machines, and backends. That is what lets
 ``BENCH_overlay.json`` gate *cycle counts of annealed placements* in CI.
+Every search runs inside :func:`search_scope`, which also pins the PRNG's
+bit stream across JAX releases.
 
 Move evaluation is O(degree), not O(E): moving node ``v`` only re-prices the
 edges incident to ``v`` (gathered from a padded host-built incidence table)
@@ -28,17 +30,33 @@ quadratic pressure delta ``2 w (load[q] - load[p] + w)``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from ..core.graph import DataflowGraph
 from .cost import CostModel, build_cost_model, edge_endpoints, torus_hops
 from .spec import AnnealConfig
+
+
+@contextlib.contextmanager
+def search_scope():
+    """Scoped JAX config of every placement-search program (and of the
+    surrogate's training-set sampling).
+
+    x64: cost totals are int64 sums of squared loads — they must not wrap on
+    big graphs, and callers shouldn't need global ``jax_enable_x64``.
+    Non-partitionable threefry: JAX 0.5 made the partitionable variant the
+    default, which draws different bits from the same key, and so moves
+    every annealed placement and the cycle counts tracked for it. The
+    searches keep the stream they were recorded with.
+    """
+    with jax.enable_x64(True), jax.threefry_partitionable(False):
+        yield
 
 
 @dataclasses.dataclass(frozen=True)
@@ -441,10 +459,8 @@ def anneal_tables(
             _thresholds(acfg), jax.random.key(acfg.seed))
     knobs = dict(nx=nx, ny=ny, rounds=acfg.rounds, steps=acfg.steps,
                  pressure_weight=acfg.pressure_weight)
-    # Scoped x64: cost totals are int64 sums of squared loads — they must not
-    # wrap on big graphs, and callers shouldn't need global jax_enable_x64.
     if guide is None:
-        with enable_x64():
+        with search_scope():
             best_pe, best_cost, init_cost = _anneal_jit(*args, **knobs)
         evals = None
     else:
@@ -461,7 +477,7 @@ def anneal_tables(
                 f"{nx}x{ny}")
         if guide_every < 1:
             raise ValueError(f"guide_every must be >= 1, got {guide_every}")
-        with enable_x64():
+        with search_scope():
             best_pe, best_cost, init_cost, evals = _anneal_guided_jit(
                 *args, guide_arrays(guide),
                 np.int64(quantize_margin(guide_margin)),
@@ -543,7 +559,7 @@ def anneal_tables_many(
         _anneal_jit, nx=nx, ny=ny, rounds=acfgs[0].rounds,
         steps=acfgs[0].steps, pressure_weight=acfgs[0].pressure_weight)
     w_node = np.asarray(w_node, np.int32)
-    with enable_x64():
+    with search_scope():
         best_pe, best_cost, init_cost = jax.vmap(
             run1, in_axes=(0, None, None, None, None, 0, 0))(
                 init_pes, nbr, w_inc, is_out, w_node, thresholds, keys)

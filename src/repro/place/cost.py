@@ -28,7 +28,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from ..core.criticality import criticality as _criticality
 from ..core.graph import DataflowGraph
@@ -93,7 +92,7 @@ class CostModel:
         return self.nx * self.ny
 
     def traffic(self, node_pe) -> jnp.ndarray:
-        with enable_x64():  # int64 accumulations must not wrap (see cost())
+        with jax.enable_x64(True):  # int64 accumulations must not wrap (see cost())
             node_pe = jnp.asarray(node_pe, jnp.int32)
             hops = torus_hops(node_pe[self.src], node_pe[self.dst],
                               self.nx, self.ny)
@@ -102,13 +101,13 @@ class CostModel:
 
     def loads(self, node_pe) -> jnp.ndarray:
         """[P] int64 criticality-weighted node load per PE."""
-        with enable_x64():
+        with jax.enable_x64(True):
             return jnp.zeros(self.num_pes, jnp.int64).at[
                 jnp.asarray(node_pe, jnp.int32)].add(
                     self.w_node.astype(jnp.int64))
 
     def pressure(self, node_pe) -> jnp.ndarray:
-        with enable_x64():
+        with jax.enable_x64(True):
             loads = self.loads(node_pe)
             return jnp.sum(loads * loads)
 
@@ -117,14 +116,14 @@ class CostModel:
 
         Runs under scoped x64 so the squared-load accumulation cannot wrap
         on large graphs (callers need no global ``jax_enable_x64``)."""
-        with enable_x64():
+        with jax.enable_x64(True):
             node_pe = jnp.asarray(node_pe, jnp.int32)
             return (self.traffic(node_pe)
                     + self.pressure_weight * self.pressure(node_pe))
 
     def batch_cost(self, placements) -> jnp.ndarray:
         """[B] int64 costs of a stacked [B, N] candidate batch (one vmap)."""
-        with enable_x64():
+        with jax.enable_x64(True):
             return jax.vmap(self.cost)(jnp.asarray(placements, jnp.int32))
 
 

@@ -79,7 +79,7 @@ def main() -> int:
     # 5. Guided annealing: delta features == batch recompute bit-exactly
     #    after a random move sequence; open gate == unguided bit-exactly;
     #    a margin-0 gate actually filters, deterministically.
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.surrogate import delta as sd
 
@@ -87,7 +87,7 @@ def main() -> int:
     ga = sd.guide_arrays(guide)
     rng = np.random.default_rng(11)
     pe = rng.integers(0, nx * ny, size=g.num_nodes).astype(np.int32)
-    with enable_x64():
+    with jax.enable_x64(True):
         st = sd.state_init(ga, pe, nx=nx, ny=ny)
         for _ in range(64):
             i = int(rng.integers(0, g.num_nodes))

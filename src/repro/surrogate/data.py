@@ -19,6 +19,7 @@ import numpy as np
 
 from ..core.graph import DataflowGraph
 from ..core.partition import place_nodes
+from ..place.anneal import search_scope
 
 #: static heuristics mixed into every sample (searchers start near these).
 _STATIC = ("round_robin", "blocked", "clustered", "bulk_clustered",
@@ -48,29 +49,30 @@ def sample_placements(g: DataflowGraph, nx: int, ny: int, n: int,
         for s in _STATIC[:min(n, len(_STATIC))]:
             out.append(place_nodes(g, num_pes, s))
 
-    kinds = ("random", "imbalanced", "perturb_rr", "perturb_cl")
-    i = 0
-    while len(out) < n:
-        k = jax.random.fold_in(key, i)
-        kind = kinds[i % len(kinds)]
-        if kind == "random":
-            pe = jax.random.randint(k, (N,), 0, num_pes, dtype=jnp.int32)
-        elif kind == "imbalanced":
-            # Confine to a PE prefix of 1/2, 1/4, or 1/8 of the grid.
-            frac = 2 ** (1 + (i // len(kinds)) % 3)
-            hi = max(1, num_pes // frac)
-            pe = jax.random.randint(k, (N,), 0, hi, dtype=jnp.int32)
-        else:
-            base = place_nodes(
-                g, num_pes, "round_robin" if kind == "perturb_rr" else "clustered")
-            # Kick 5% / 20% / 50% of nodes to uniform-random PEs.
-            permille = (50, 200, 500)[(i // len(kinds)) % 3]
-            k1, k2 = jax.random.split(k)
-            move = jax.random.randint(k1, (N,), 0, 1000, dtype=jnp.int32) < permille
-            rand = jax.random.randint(k2, (N,), 0, num_pes, dtype=jnp.int32)
-            pe = jnp.where(move, rand, jnp.asarray(base))
-        out.append(np.asarray(pe, dtype=np.int32))
-        i += 1
+    with search_scope():  # the PRNG stream the training sets were made with
+        kinds = ("random", "imbalanced", "perturb_rr", "perturb_cl")
+        i = 0
+        while len(out) < n:
+            k = jax.random.fold_in(key, i)
+            kind = kinds[i % len(kinds)]
+            if kind == "random":
+                pe = jax.random.randint(k, (N,), 0, num_pes, dtype=jnp.int32)
+            elif kind == "imbalanced":
+                # Confine to a PE prefix of 1/2, 1/4, or 1/8 of the grid.
+                frac = 2 ** (1 + (i // len(kinds)) % 3)
+                hi = max(1, num_pes // frac)
+                pe = jax.random.randint(k, (N,), 0, hi, dtype=jnp.int32)
+            else:
+                base = place_nodes(g, num_pes, "round_robin"
+                                   if kind == "perturb_rr" else "clustered")
+                # Kick 5% / 20% / 50% of nodes to uniform-random PEs.
+                permille = (50, 200, 500)[(i // len(kinds)) % 3]
+                k1, k2 = jax.random.split(k)
+                move = jax.random.randint(k1, (N,), 0, 1000, dtype=jnp.int32) < permille
+                rand = jax.random.randint(k2, (N,), 0, num_pes, dtype=jnp.int32)
+                pe = jnp.where(move, rand, jnp.asarray(base))
+            out.append(np.asarray(pe, dtype=np.int32))
+            i += 1
     return np.stack(out[:n]).astype(np.int32)
 
 

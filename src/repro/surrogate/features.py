@@ -55,7 +55,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from ..core.criticality import asap_levels
 from ..core.graph import DataflowGraph
@@ -184,7 +183,7 @@ class FeatureExtractor:
             raise ValueError(
                 f"placement references PEs outside the {self.nx}x{self.ny} "
                 f"grid")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = self._batch_fn(jnp.asarray(placements))
             return np.asarray(out).astype(np.float64)
 

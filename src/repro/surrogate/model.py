@@ -6,8 +6,9 @@ equations
 
     (Xs' Xs + ridge * n * I) beta = Xs' (y - mean(y))
 
-once, in float64 under scoped x64 (``jnp.linalg.solve`` — no iterative
-optimizer, no learning-rate knobs, no RNG). For fixed inputs the
+once, in float64 under scoped x64 on the host's CPU device
+(``jnp.linalg.solve`` — no iterative optimizer, no learning-rate knobs, no
+RNG). For fixed inputs the
 coefficients are bit-reproducible run to run, which is what lets tests pin
 them with ``assert_array_equal`` and lets CI gate rank quality.
 
@@ -19,9 +20,9 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .features import FeatureExtractor
 
@@ -74,7 +75,12 @@ def fit_features(
     sigma = x.std(axis=0)
     sigma = np.where(sigma == 0, 1.0, sigma)
     y_mean = float(y.mean())
-    with enable_x64():
+    # A deliberate host computation: the system is [F, F] with F ~ 30, and
+    # XLA:TPU has no float64 LU ("Only F32 and C64 types are implemented in
+    # LuDecomposition"). Pinning it to the CPU device on every backend runs
+    # the same XLA:CPU program everywhere, so beta stays bit-identical across
+    # machines (numpy's LAPACK solve differs from it in the last bits).
+    with jax.enable_x64(True), jax.default_device(jax.devices("cpu")[0]):
         xs = (jnp.asarray(x) - jnp.asarray(mu)) / jnp.asarray(sigma)
         yc = jnp.asarray(y) - y_mean
         gram = xs.T @ xs + ridge * x.shape[0] * jnp.eye(x.shape[1])

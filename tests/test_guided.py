@@ -6,9 +6,9 @@ guide's coarse-level features equal the fine features of the projected
 placement, and the guide knobs thread through PlacementSpec/resolve."""
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro import place, surrogate
 from repro.core import workloads as wl
@@ -37,7 +37,7 @@ def test_delta_features_match_batch_recompute_bit_exactly(model):
     ex = guide.extractor
     rng = np.random.default_rng(7)
     pe = rng.integers(0, NX * NY, size=G.num_nodes).astype(np.int32)
-    with enable_x64():
+    with jax.enable_x64(True):
         st = sd.state_init(ga, pe, nx=NX, ny=NY)
         np.testing.assert_array_equal(
             np.asarray(st.feats), ex.features_batch(pe)[0].astype(np.int64))
@@ -61,7 +61,7 @@ def test_delta_score_is_quantized_prediction_delta(model):
     pe = rng.integers(0, NX * NY, size=G.num_nodes).astype(np.int32)
     pe2 = pe.copy()
     pe2[11] = (pe[11] + 3) % (NX * NY)
-    with enable_x64():
+    with jax.enable_x64(True):
         st = sd.state_init(ga, pe, nx=NX, ny=NY)
         _, dscore = sd.apply_move(ga, st, pe, 11, np.int32(pe2[11]),
                                   nx=NX, ny=NY)

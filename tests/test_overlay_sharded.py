@@ -1,5 +1,6 @@
-"""shard_map overlay: cycle-exact equivalence with the single-device sim
-(subprocess with 8 fake host devices)."""
+"""shard_map overlay: cycle-exact equivalence with the single-device sim,
+and the megakernel refusing a multi-shard mesh (subprocesses with fake host
+devices)."""
 import os
 import subprocess
 import sys
@@ -28,10 +29,44 @@ print("SHARDED_EXACT_OK")
 """
 
 
-@pytest.mark.slow
-def test_sharded_overlay_cycle_exact():
+MEGAKERNEL_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys; sys.path.insert(0, "src")
+import repro
+from repro.core import workloads as wl
+from repro.core.overlay import OverlayConfig
+from repro.core.partition import build_graph_memory
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(2, 2)
+gm = build_graph_memory(wl.layered_dag(4, 6, seed=3), 2, 2)
+cfg = OverlayConfig(engine="megakernel")
+for call in (lambda: repro.run(gm, cfg, mesh=mesh),
+             lambda: repro.run(gm, mesh=mesh, batch=[cfg, cfg])):
+    try:
+        call()
+    except ValueError as e:
+        assert "multi-shard mesh" in str(e), e
+    else:
+        raise AssertionError("engine='megakernel' ran on a 2x2 mesh")
+print("MEGAKERNEL_MESH_RAISES_OK")
+"""
+
+
+def _run_script(script: str, timeout: int):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", SCRIPT], cwd=os.getcwd(),
-                         capture_output=True, text=True, env=env, timeout=420)
+    return subprocess.run([sys.executable, "-c", script], cwd=os.getcwd(),
+                          capture_output=True, text=True, env=env,
+                          timeout=timeout)
+
+
+@pytest.mark.slow
+def test_sharded_overlay_cycle_exact():
+    out = _run_script(SCRIPT, 420)
     assert "SHARDED_EXACT_OK" in out.stdout, out.stderr[-2000:]
+
+
+def test_megakernel_on_multi_shard_mesh_raises():
+    out = _run_script(MEGAKERNEL_SCRIPT, 120)
+    assert "MEGAKERNEL_MESH_RAISES_OK" in out.stdout, out.stderr[-2000:]

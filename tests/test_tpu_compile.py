@@ -1,0 +1,107 @@
+"""The main path's Pallas kernels and chunk programs compile for TPU v5e.
+
+The chip is described, not attached: ``jax.experimental.topologies``
+describes a v5e:2x2 host, and each program is lowered for one of its
+devices from shapes alone and compiled by the TPU compiler installed with
+JAX. What that compiler refuses here would be refused on the chip. Nothing
+runs, so these tests say nothing about results or times.
+
+Code that asks ``jax.default_backend()`` still sees the CPU here and would
+lower the kernels as interpreted jnp, so every test forces the compiled
+path itself (``interpret=False``, or ``ops._interpret`` patched) and checks
+that a kernel (``tpu_custom_call``) is really in the compiled program.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import overlay
+from repro.core import workloads as wl
+from repro.core.overlay import OverlayConfig
+from repro.core.partition import build_graph_memory
+from repro.kernels import lod, megakernel, ops
+
+#: One block of 256 PE rows at the paper-scale width: L = 1856 slots per PE
+#: (fig1 arrow-LU, 470,772 nodes on 16x16) is 58 32-bit RDY words.
+ROWS, WORDS = 256, 58
+GRID = 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2 host, with JAX's persistent
+    compilation cache off: a compile for a described chip is written to the
+    cache but cannot be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def fig1_graph():
+    """The 15,072-node fig1 graph packed on 16x16, criticality-ordered."""
+    gm = build_graph_memory(wl.arrow_lu_graph(4, 10, 8, seed=3), GRID, GRID,
+                            criticality_order=True)
+    return dict(overlay.device_graph(gm))
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("kernel", ["lod", "schedule_step",
+                                    "rotating_schedule_step"])
+def test_lod_kernel_compiles(one_chip, kernel):
+    bits = jax.ShapeDtypeStruct((ROWS, WORDS), jnp.uint32, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
+    args = {"lod": (bits,), "schedule_step": (bits, row),
+            "rotating_schedule_step": (bits, row, row)}[kernel]
+    compiled = getattr(lod, kernel).lower(*args, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _compile_chunk(graph, sharding, engine):
+    cfg = OverlayConfig(engine=engine)
+    L = graph["opcode"].shape[2]
+    K = overlay.resolve_check_every(cfg, GRID, GRID, L, backend="tpu")
+    state = jax.eval_shape(lambda g: overlay.init_state(g, cfg), graph)
+    chunk = jax.jit(
+        lambda g, s: overlay.make_engine_chunk_fn(g, cfg, K)(s))
+    return chunk.lower(_shapes(graph, sharding),
+                       _shapes(state, sharding)).compile()
+
+
+@pytest.mark.parametrize("engine,has_kernel", [("jnp", False),
+                                               ("select", True)])
+def test_chunk_program_compiles(one_chip, fig1_graph, monkeypatch, engine,
+                                has_kernel):
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    compiled = _compile_chunk(fig1_graph, one_chip, engine)
+    assert ("tpu_custom_call" in compiled.as_text()) == has_kernel
+
+
+def test_megakernel_is_refused(one_chip, fig1_graph, monkeypatch):
+    # The per-PE [nx, ny, L] gathers of the cycle body, traced into one
+    # kernel, are more than the TPU kernel compiler lowers. Once this
+    # passes no more, the megakernel compiles: measure it on the chip.
+    monkeypatch.setattr(megakernel, "_interpret", lambda: False)
+    with pytest.raises(NotImplementedError, match="Only 2D gather"):
+        _compile_chunk(fig1_graph, one_chip, "megakernel")
