@@ -44,7 +44,21 @@ def run(graph_or_gm, cfg=None, *, mesh=None, batch: Sequence | None = None,
     Returns:
       :class:`repro.core.overlay.SimResult` (or a list of them with
       ``batch=``).
+
+    Under an open ``jax.profiler`` session the call is the host span
+    ``repro.run``, with the spans of its steps inside (see
+    :data:`repro.HOST_SPANS` and docs/telemetry.md).
     """
+    import jax
+
+    from . import SPAN_RUN
+
+    with jax.profiler.TraceAnnotation(SPAN_RUN):
+        return _dispatch(graph_or_gm, cfg, mesh, batch, nx, ny)
+
+
+def _dispatch(graph_or_gm, cfg, mesh, batch, nx, ny):
+    """The engine path :func:`run`'s arguments pick, called with them."""
     if batch is not None:
         if cfg is not None:
             raise ValueError(

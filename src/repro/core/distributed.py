@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import SPAN_DISPATCH, SPAN_TO_DEVICE, SPAN_UNPACK
 from . import bitvec, overlay, schedulers
 from .partition import GraphMemory
 
@@ -123,7 +124,8 @@ def _simulate_sharded(gm: GraphMemory, mesh: Mesh,
     cfg = cfg or overlay.OverlayConfig()
     gm = overlay._as_memory(gm, cfg, nx, ny)
     sched = schedulers.get(cfg.scheduler)
-    g = overlay.device_graph(gm)
+    with jax.profiler.TraceAnnotation(SPAN_TO_DEVICE):
+        g = overlay.device_graph(gm)
     K = overlay.resolve_check_every(cfg, gm.nx, gm.ny, g["opcode"].shape[2],
                                     num_devices=mesh.size)
 
@@ -181,7 +183,10 @@ def _simulate_sharded(gm: GraphMemory, mesh: Mesh,
             out["telem"] = _gather_telem(final["telem"], axis_x, axis_y)
         return out
 
-    return overlay._unpack_result(run(dict(g)), gm, cfg=cfg)
+    with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+        final = run(dict(g))
+    with jax.profiler.TraceAnnotation(SPAN_UNPACK):
+        return overlay._unpack_result(final, gm, cfg=cfg)
 
 
 def simulate_sharded(gm: GraphMemory, mesh: Mesh,
@@ -257,14 +262,16 @@ def _simulate_batch_sharded(gm: GraphMemory, mesh: Mesh,
         cfgs[0], scheduler=names[0], select_latency=None,
         max_cycles=max(c.max_cycles for c in cfgs))
     sched = schedulers.BatchedScheduler(tuple(names))
-    g = overlay.device_graph(gm)
-    L = g["opcode"].shape[2]
-    num_words = L // bitvec.FLAGS_PER_WORD
-    policy_ids = jnp.asarray([names.index(c.scheduler) for c in cfgs], jnp.int32)
-    sel_lats = jnp.asarray(
-        [schedulers.get(c.scheduler).sel_lat(c, num_words) for c in cfgs],
-        jnp.int32)
-    max_cycs = jnp.asarray([c.max_cycles for c in cfgs], jnp.int32)
+    with jax.profiler.TraceAnnotation(SPAN_TO_DEVICE):
+        g = overlay.device_graph(gm)
+        L = g["opcode"].shape[2]
+        num_words = L // bitvec.FLAGS_PER_WORD
+        policy_ids = jnp.asarray([names.index(c.scheduler) for c in cfgs],
+                                 jnp.int32)
+        sel_lats = jnp.asarray(
+            [schedulers.get(c.scheduler).sel_lat(c, num_words) for c in cfgs],
+            jnp.int32)
+        max_cycs = jnp.asarray([c.max_cycles for c in cfgs], jnp.int32)
     K = overlay.resolve_check_every(base, gm.nx, gm.ny, L,
                                     num_devices=mesh.size)
 
@@ -345,9 +352,11 @@ def _simulate_batch_sharded(gm: GraphMemory, mesh: Mesh,
             out["telem"] = _gather_telem(final["telem"], axis_x, axis_y)
         return out
 
-    final = run(dict(g), policy_ids, sel_lats, max_cycs)
-    return [overlay._unpack_result(final, gm, b, cfg=base)
-            for b in range(len(cfgs))]
+    with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+        final = run(dict(g), policy_ids, sel_lats, max_cycs)
+    with jax.profiler.TraceAnnotation(SPAN_UNPACK):
+        return [overlay._unpack_result(final, gm, b, cfg=base)
+                for b in range(len(cfgs))]
 
 
 def simulate_batch_sharded(gm: GraphMemory, mesh: Mesh,

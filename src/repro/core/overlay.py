@@ -41,6 +41,13 @@ Hot-path engineering (engine-level, never observable in results):
     cycles in kernel refs (:mod:`repro.kernels.megakernel`); the jnp scan
     above stays the bit-exact reference oracle.
 
+Profiling: the cycle body's stages run under ``jax.named_scope``s
+(``ovl.inject``, ``ovl.route``, ``ovl.eject_fire``, ``ovl.select``,
+``ovl.terminate``; ``ovl.chunk_repair`` around a chunk's repair), which
+only name the compiled ops, and the host steps of an engine call under
+``jax.profiler.TraceAnnotation`` spans (``repro.pack``, ``repro.to_device``,
+``repro.dispatch``, ``repro.unpack``). The names live in :mod:`repro`.
+
 Three execution engines share the same cycle body:
   * :func:`simulate`          — single device, one config;
   * :func:`simulate_batch`    — one device, a *stacked* config axis: the body
@@ -61,6 +68,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import (
+    SCOPE_CHUNK_REPAIR, SCOPE_EJECT_FIRE, SCOPE_INJECT, SCOPE_ROUTE,
+    SCOPE_SELECT, SCOPE_TERMINATE, SPAN_DISPATCH, SPAN_PACK, SPAN_TO_DEVICE,
+    SPAN_UNPACK,
+)
 from . import bitvec, noc, schedulers
 from .graph import DIV_EPS, OP_ADD, OP_DIV, OP_MUL, OP_SUB, DataflowGraph
 from .partition import GraphMemory
@@ -356,149 +368,168 @@ def make_cycle_fn(
         from ..telemetry import trace as telemetry_trace  # lazy, like place
 
     def cycle(s):
-        # ---- 1. offer injection packet from the active node's fanout cursor
-        inj_valid = (s["active"] >= 0) & (s["cursor"] < s["cursor_end"])
-        dst_pe = _row_gather(g["e_dst_pe"], s["cursor"])
-        inject = dict(
-            valid=inj_valid,
-            dst_x=dst_pe // ny_i32,
-            dst_y=dst_pe % ny_i32,
-            dst_slot=_row_gather(g["e_dst_slot"], s["cursor"]),
-            opidx=_row_gather(g["e_dst_opidx"], s["cursor"]),
-            value=_row_gather(s["value"], s["active"]),
-        )
+        with jax.named_scope(SCOPE_INJECT):
+            # ---- 1. offer injection packet from the active node's fanout
+            # cursor
+            inj_valid = (s["active"] >= 0) & (s["cursor"] < s["cursor_end"])
+            dst_pe = _row_gather(g["e_dst_pe"], s["cursor"])
+            inject = dict(
+                valid=inj_valid,
+                dst_x=dst_pe // ny_i32,
+                dst_y=dst_pe % ny_i32,
+                dst_slot=_row_gather(g["e_dst_slot"], s["cursor"]),
+                opidx=_row_gather(g["e_dst_opidx"], s["cursor"]),
+                value=_row_gather(s["value"], s["active"]),
+            )
 
-        # ---- 2. NoC cycle
-        link_e, link_s, ejects, accepted, deflected = noc.router_cycle(
-            s["link_e"], s["link_s"], inject, shift_e=shift_e, shift_s=shift_s,
-            x0=x0, y0=y0, eject_capacity=cfg.eject_capacity,
-            eject_policy=cfg.eject_policy,
-        )
+        with jax.named_scope(SCOPE_ROUTE):
+            # ---- 2. NoC cycle
+            link_e, link_s, ejects, accepted, deflected = noc.router_cycle(
+                s["link_e"], s["link_s"], inject, shift_e=shift_e,
+                shift_s=shift_s, x0=x0, y0=y0,
+                eject_capacity=cfg.eject_capacity,
+                eject_policy=cfg.eject_policy,
+            )
 
-        # ---- 3. advance fanout cursor; retire drained nodes
-        cursor = s["cursor"] + accepted.astype(jnp.int32)
-        cursor_end = s["cursor_end"]
-        drained = (s["active"] >= 0) & (cursor >= cursor_end)
-        active = jnp.where(drained, -1, s["active"])
-        sel_wait = jnp.where(drained, s["sel_lat"] - 1, s["sel_wait"])
+            # ---- 3. advance fanout cursor; retire drained nodes
+            cursor = s["cursor"] + accepted.astype(jnp.int32)
+            cursor_end = s["cursor_end"]
+            drained = (s["active"] >= 0) & (cursor >= cursor_end)
+            active = jnp.where(drained, -1, s["active"])
+            sel_wait = jnp.where(drained, s["sel_lat"] - 1, s["sel_wait"])
 
-        # ---- 4. apply ejected packets, fused across the eject ports.
-        # All eject ports apply as ONE stacked [E, nx, ny] scatter per array
-        # instead of ``eject_capacity`` sequential full-grid gather/scatter
-        # rounds, and fire detection stays in gathered [E, nx, ny] form, so
-        # the per-cycle cost is O(PEs), not O(PEs x slots). Order-free
-        # exactness relies on the graph-memory invariants that each
-        # (pe, slot, opidx) operand cell receives exactly one packet over the
-        # whole run (fanin semantics) and each slot fires at most once, so
-        # scatter-*add* into the zero-initialized cells equals the
-        # sequential writes of the per-port loop this replaces.
-        ix = jnp.arange(nx)[:, None] * jnp.ones((1, ny), jnp.int32)
-        iy = jnp.arange(ny)[None, :] * jnp.ones((nx, 1), jnp.int32)
-        sched_st = s["sched"]
+        with jax.named_scope(SCOPE_EJECT_FIRE):
+            # ---- 4. apply ejected packets, fused across the eject ports.
+            # All eject ports apply as ONE stacked [E, nx, ny] scatter per
+            # array instead of ``eject_capacity`` sequential full-grid
+            # gather/scatter rounds, and fire detection stays in gathered
+            # [E, nx, ny] form, so the per-cycle cost is O(PEs), not
+            # O(PEs x slots). Order-free exactness relies on the graph-memory
+            # invariants that each (pe, slot, opidx) operand cell receives
+            # exactly one packet over the whole run (fanin semantics) and
+            # each slot fires at most once, so scatter-*add* into the
+            # zero-initialized cells equals the sequential writes of the
+            # per-port loop this replaces.
+            ix = jnp.arange(nx)[:, None] * jnp.ones((1, ny), jnp.int32)
+            iy = jnp.arange(ny)[None, :] * jnp.ones((nx, 1), jnp.int32)
+            sched_st = s["sched"]
 
-        ej_valid = jnp.stack([e["valid"] for e in ejects])          # [E,nx,ny]
-        ej_slot = jnp.clip(jnp.stack([e["dst_slot"] for e in ejects]), 0, L - 1)
-        ej_op = jnp.clip(jnp.stack([e["opidx"] for e in ejects]), 0, 1)
-        ej_val = jnp.stack([e["value"] for e in ejects])
+            ej_valid = jnp.stack([e["valid"] for e in ejects])  # [E,nx,ny]
+            ej_slot = jnp.clip(jnp.stack([e["dst_slot"] for e in ejects]),
+                               0, L - 1)
+            ej_op = jnp.clip(jnp.stack([e["opidx"] for e in ejects]), 0, 1)
+            ej_val = jnp.stack([e["value"] for e in ejects])
 
-        # With one eject port the (pe, slot) scatter indices are unique and
-        # iterate in row-major order — tell XLA so it takes the fast path.
-        E = len(ejects)
-        hints = dict(mode="promise_in_bounds",
-                     unique_indices=E == 1, indices_are_sorted=E == 1)
-        operands = s["operands"].at[ix[None], iy[None], ej_slot, ej_op].add(
-            jnp.where(ej_valid, ej_val, 0.0), **hints)
-        pending = s["pending"].at[ix[None], iy[None], ej_slot].add(
-            -ej_valid.astype(jnp.int32), **hints)
+            # With one eject port the (pe, slot) scatter indices are unique
+            # and iterate in row-major order — tell XLA so it takes the fast
+            # path.
+            E = len(ejects)
+            hints = dict(mode="promise_in_bounds",
+                         unique_indices=E == 1, indices_are_sorted=E == 1)
+            operands = s["operands"].at[
+                ix[None], iy[None], ej_slot, ej_op].add(
+                    jnp.where(ej_valid, ej_val, 0.0), **hints)
+            pending = s["pending"].at[ix[None], iy[None], ej_slot].add(
+                -ej_valid.astype(jnp.int32), **hints)
 
-        # A slot fires the cycle a delivery drops its pending count to zero.
-        # Gathered at each port's own target slot; when two ports hit the
-        # same slot in one cycle both see the post-decrement count, so the
-        # first port claims the fire (same single fire, same operands and
-        # value, as the sequential loop).
-        new_pend = jnp.stack([_row_gather(pending, ej_slot[e])
-                              for e in range(E)])
-        was_done = jnp.stack([_row_gather(s["computed"], ej_slot[e])
-                              for e in range(E)])
-        fired = ej_valid & (new_pend == 0) & ~was_done
-        for e in range(1, E):
-            for prev in range(e):
-                dup = fired[prev] & (ej_slot[prev] == ej_slot[e])
-                fired = fired.at[e].set(fired[e] & ~dup)
+            # A slot fires the cycle a delivery drops its pending count to
+            # zero. Gathered at each port's own target slot; when two ports
+            # hit the same slot in one cycle both see the post-decrement
+            # count, so the first port claims the fire (same single fire,
+            # same operands and value, as the sequential loop).
+            new_pend = jnp.stack([_row_gather(pending, ej_slot[e])
+                                  for e in range(E)])
+            was_done = jnp.stack([_row_gather(s["computed"], ej_slot[e])
+                                  for e in range(E)])
+            fired = ej_valid & (new_pend == 0) & ~was_done
+            for e in range(1, E):
+                for prev in range(e):
+                    dup = fired[prev] & (ej_slot[prev] == ej_slot[e])
+                    fired = fired.at[e].set(fired[e] & ~dup)
 
-        opnds = jnp.stack([_row_gather(operands, ej_slot[e])
-                           for e in range(E)])                 # [E,nx,ny,2]
-        opc = jnp.stack([_row_gather(g["opcode"], ej_slot[e])
-                         for e in range(E)])
-        fval = alu(opc, opnds[..., 0], opnds[..., 1])
-        value = s["value"].at[ix[None], iy[None], ej_slot].add(
-            jnp.where(fired, fval, 0.0), **hints)
-        computed = s["computed"].at[ix[None], iy[None], ej_slot].max(
-            fired, mode="promise_in_bounds")
+            opnds = jnp.stack([_row_gather(operands, ej_slot[e])
+                               for e in range(E)])  # [E,nx,ny,2]
+            opc = jnp.stack([_row_gather(g["opcode"], ej_slot[e])
+                             for e in range(E)])
+            fval = alu(opc, opnds[..., 0], opnds[..., 1])
+            value = s["value"].at[ix[None], iy[None], ej_slot].add(
+                jnp.where(fired, fval, 0.0), **hints)
+            computed = s["computed"].at[ix[None], iy[None], ej_slot].max(
+                fired, mode="promise_in_bounds")
 
-        # Enqueue fired nodes in eject-port order (per-PE FIFO arrival
-        # semantics are exactly the sequential loop's).
-        for e in range(E):
-            ready_e = fired[e] & (_row_gather(g["fo_count"], ej_slot[e]) > 0)
-            sched_st = sched.on_ready(sched_st, ix, iy, ej_slot[e], ready_e)
+            # Enqueue fired nodes in eject-port order (per-PE FIFO arrival
+            # semantics are exactly the sequential loop's).
+            for e in range(E):
+                ready_e = fired[e] & (
+                    _row_gather(g["fo_count"], ej_slot[e]) > 0)
+                sched_st = sched.on_ready(sched_st, ix, iy, ej_slot[e],
+                                          ready_e)
 
-        n_delivered = ej_valid.sum().astype(jnp.int32)
-        n_fired = fired.sum().astype(jnp.int32)
+            n_delivered = ej_valid.sum().astype(jnp.int32)
+            n_fired = fired.sum().astype(jnp.int32)
 
-        # ---- 5. scheduler: select (and consume) the next node on idle PEs
-        idle = active < 0
-        gate = idle & (sel_wait == 0)
-        if telem_spec is not None and telem_spec.sched:
-            # Ready-set depth as the scheduler sees it at pick time: after
-            # this cycle's fires enqueued, before the pick consumes.
-            rdy_depth = sched.ready_depth(sched_st)
-        cand, have, sched_st = sched.step(sched_st, idle, gate,
-                                          use_pallas=cfg.engine == "select")
-        can_wait = idle & have & (sel_wait > 0)
-        sel_wait = jnp.where(can_wait, sel_wait - 1, sel_wait)
-        sel = gate & have
+        with jax.named_scope(SCOPE_SELECT):
+            # ---- 5. scheduler: select (and consume) the next node on idle
+            # PEs
+            idle = active < 0
+            gate = idle & (sel_wait == 0)
+            if telem_spec is not None and telem_spec.sched:
+                # Ready-set depth as the scheduler sees it at pick time:
+                # after this cycle's fires enqueued, before the pick consumes.
+                rdy_depth = sched.ready_depth(sched_st)
+            cand, have, sched_st = sched.step(
+                sched_st, idle, gate, use_pallas=cfg.engine == "select")
+            can_wait = idle & have & (sel_wait > 0)
+            sel_wait = jnp.where(can_wait, sel_wait - 1, sel_wait)
+            sel = gate & have
 
-        active = jnp.where(sel, cand, active)
-        new_base = _row_gather(g["fo_base"], jnp.clip(cand, 0, L - 1))
-        new_cnt = _row_gather(g["fo_count"], jnp.clip(cand, 0, L - 1))
-        cursor = jnp.where(sel, new_base, cursor)
-        cursor_end = jnp.where(sel, new_base + new_cnt, cursor_end)
+            active = jnp.where(sel, cand, active)
+            new_base = _row_gather(g["fo_base"], jnp.clip(cand, 0, L - 1))
+            new_cnt = _row_gather(g["fo_count"], jnp.clip(cand, 0, L - 1))
+            cursor = jnp.where(sel, new_base, cursor)
+            cursor_end = jnp.where(sel, new_base + new_cnt, cursor_end)
 
-        # ---- 6. termination + stats. ``remaining`` counts local uncomputed
-        # valid nodes so the all-computed predicate is O(1) per cycle instead
-        # of an O(slots) reduction over the computed plane.
-        remaining = s["remaining"] - n_fired
-        all_computed = all_reduce(remaining == 0)
-        no_ready = all_reduce(sched.empty(sched_st))
-        no_active = all_reduce((active < 0).all())
-        links_idle = all_reduce(noc.links_empty(link_e, link_s))
-        done = all_computed & no_ready & no_active & links_idle
+        with jax.named_scope(SCOPE_TERMINATE):
+            # ---- 6. termination + stats. ``remaining`` counts local
+            # uncomputed valid nodes so the all-computed predicate is O(1)
+            # per cycle instead of an O(slots) reduction over the computed
+            # plane.
+            remaining = s["remaining"] - n_fired
+            all_computed = all_reduce(remaining == 0)
+            no_ready = all_reduce(sched.empty(sched_st))
+            no_active = all_reduce((active < 0).all())
+            links_idle = all_reduce(noc.links_empty(link_e, link_s))
+            done = all_computed & no_ready & no_active & links_idle
 
-        # Deflections, split by cause (see noc.router_cycle): a blocked PE
-        # injection keeps the packet circulating in the PE just as a lost
-        # S-turn keeps it circulating on the ring, so both count as NoC
-        # (route-contention) deflections; eject-port losers count separately.
-        # ``deflections`` stays their sum — bit-exactly the pre-split stat.
-        inj_blocked = inj_valid & ~accepted
-        d_noc = all_reduce(
-            inj_blocked.sum() + deflected["noc"].sum()).astype(jnp.int32)
-        d_ej = all_reduce(deflected["eject"].sum()).astype(jnp.int32)
+            # Deflections, split by cause (see noc.router_cycle): a blocked
+            # PE injection keeps the packet circulating in the PE just as a
+            # lost S-turn keeps it circulating on the ring, so both count as
+            # NoC (route-contention) deflections; eject-port losers count
+            # separately. ``deflections`` stays their sum — bit-exactly the
+            # pre-split stat.
+            inj_blocked = inj_valid & ~accepted
+            d_noc = all_reduce(
+                inj_blocked.sum() + deflected["noc"].sum()).astype(jnp.int32)
+            d_ej = all_reduce(deflected["eject"].sum()).astype(jnp.int32)
 
-        out = dict(
-            pending=pending, operands=operands, computed=computed, value=value,
-            remaining=remaining,
-            sched=sched_st,
-            active=active, cursor=cursor, cursor_end=cursor_end,
-            sel_lat=s["sel_lat"], sel_wait=sel_wait,
-            link_e=link_e, link_s=link_s,
-            cycle=s["cycle"] + 1,
-            delivered=s["delivered"] + all_reduce(n_delivered).astype(jnp.int32),
-            noc_deflections=s["noc_deflections"] + d_noc,
-            eject_deflections=s["eject_deflections"] + d_ej,
-            deflections=s["deflections"] + d_noc + d_ej,
-            busy_cycles=s["busy_cycles"] + all_reduce(n_fired).astype(jnp.int32),
-            done=done,
-        )
+            out = dict(
+                pending=pending, operands=operands, computed=computed,
+                value=value,
+                remaining=remaining,
+                sched=sched_st,
+                active=active, cursor=cursor, cursor_end=cursor_end,
+                sel_lat=s["sel_lat"], sel_wait=sel_wait,
+                link_e=link_e, link_s=link_s,
+                cycle=s["cycle"] + 1,
+                delivered=s["delivered"]
+                + all_reduce(n_delivered).astype(jnp.int32),
+                noc_deflections=s["noc_deflections"] + d_noc,
+                eject_deflections=s["eject_deflections"] + d_ej,
+                deflections=s["deflections"] + d_noc + d_ej,
+                busy_cycles=s["busy_cycles"]
+                + all_reduce(n_fired).astype(jnp.int32),
+                done=done,
+            )
         if telem_spec is not None:
             # Observer only: every input below is shard-local and already
             # computed by the model above; nothing feeds back into it.
@@ -567,7 +598,8 @@ def make_chunk_fn(cycle_fn, check_every: int,
 
     def chunk(s):
         keys = stat_keys(s)
-        start_stats = jnp.stack([s[k] for k in keys])
+        with jax.named_scope(SCOPE_CHUNK_REPAIR):
+            start_stats = jnp.stack([s[k] for k in keys])
         start_cycle = s["cycle"]
         start_done = s["done"]  # already-finished batch elements re-enter
 
@@ -577,21 +609,23 @@ def make_chunk_fn(cycle_fn, check_every: int,
 
         s2, done_trace = jax.lax.scan(body, s, None, length=check_every)
 
-        done_trace = all_reduce(done_trace)            # one collective
-        any_done = done_trace.any()
-        first = jnp.argmax(done_trace).astype(jnp.int32)
-        cycle = jnp.where(
-            start_done, start_cycle,
-            jnp.where(any_done, start_cycle + first + 1, s2["cycle"]))
+        with jax.named_scope(SCOPE_CHUNK_REPAIR):
+            done_trace = all_reduce(done_trace)            # one collective
+            any_done = done_trace.any()
+            first = jnp.argmax(done_trace).astype(jnp.int32)
+            cycle = jnp.where(
+                start_done, start_cycle,
+                jnp.where(any_done, start_cycle + first + 1, s2["cycle"]))
 
-        end_stats = jnp.stack([s2[k] for k in keys])
-        stats = start_stats + all_reduce(end_stats - start_stats)
+            end_stats = jnp.stack([s2[k] for k in keys])
+            stats = start_stats + all_reduce(end_stats - start_stats)
 
-        out = dict(s2, done=any_done, cycle=cycle)
-        for i, k in enumerate(keys):
-            out[k] = stats[i]
-        if "telem" in out:
-            out["telem"] = repair_telemetry(out["telem"], s2["cycle"] - cycle)
+            out = dict(s2, done=any_done, cycle=cycle)
+            for i, k in enumerate(keys):
+                out[k] = stats[i]
+            if "telem" in out:
+                out["telem"] = repair_telemetry(out["telem"],
+                                                s2["cycle"] - cycle)
         return out
 
     return chunk
@@ -701,7 +735,8 @@ def _as_memory(gm, cfg: OverlayConfig, nx: int | None, ny: int | None):
                 "pass nx= and ny=")
         from ..place.api import graph_memory_for_config
 
-        return graph_memory_for_config(gm, nx, ny, cfg)
+        with jax.profiler.TraceAnnotation(SPAN_PACK):
+            return graph_memory_for_config(gm, nx, ny, cfg)
     raise TypeError(f"expected GraphMemory or DataflowGraph, got {type(gm)}")
 
 
@@ -719,9 +754,12 @@ def _simulate(gm: GraphMemory | DataflowGraph,
     """
     cfg = cfg or OverlayConfig()
     gm = _as_memory(gm, cfg, nx, ny)
-    g = device_graph(gm)
-    final = _run_jit(dict(g), cfg, gm.nx, gm.ny)
-    return _unpack_result(final, gm, cfg=cfg)
+    with jax.profiler.TraceAnnotation(SPAN_TO_DEVICE):
+        g = device_graph(gm)
+    with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+        final = _run_jit(dict(g), cfg, gm.nx, gm.ny)
+    with jax.profiler.TraceAnnotation(SPAN_UNPACK):
+        return _unpack_result(final, gm, cfg=cfg)
 
 
 def simulate(gm: GraphMemory | DataflowGraph, cfg: OverlayConfig | None = None,
@@ -856,17 +894,22 @@ def _simulate_batch(gm: GraphMemory | DataflowGraph,
     base = dataclasses.replace(
         cfgs[0], scheduler=names[0], select_latency=None,
         max_cycles=max(c.max_cycles for c in cfgs))
-    g = device_graph(gm)
-    num_words = g["opcode"].shape[2] // bitvec.FLAGS_PER_WORD
-    policy_ids = jnp.asarray([names.index(c.scheduler) for c in cfgs], jnp.int32)
-    sel_lats = jnp.asarray(
-        [schedulers.get(c.scheduler).sel_lat(c, num_words) for c in cfgs],
-        jnp.int32)
-    max_cycs = jnp.asarray([c.max_cycles for c in cfgs], jnp.int32)
+    with jax.profiler.TraceAnnotation(SPAN_TO_DEVICE):
+        g = device_graph(gm)
+        num_words = g["opcode"].shape[2] // bitvec.FLAGS_PER_WORD
+        policy_ids = jnp.asarray([names.index(c.scheduler) for c in cfgs],
+                                 jnp.int32)
+        sel_lats = jnp.asarray(
+            [schedulers.get(c.scheduler).sel_lat(c, num_words) for c in cfgs],
+            jnp.int32)
+        max_cycs = jnp.asarray([c.max_cycles for c in cfgs], jnp.int32)
 
-    final = _run_batch_jit(dict(g), base, tuple(names), policy_ids, sel_lats,
-                           max_cycs, gm.nx, gm.ny)
-    return [_unpack_result(final, gm, b, cfg=base) for b in range(len(cfgs))]
+    with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+        final = _run_batch_jit(dict(g), base, tuple(names), policy_ids,
+                               sel_lats, max_cycs, gm.nx, gm.ny)
+    with jax.profiler.TraceAnnotation(SPAN_UNPACK):
+        return [_unpack_result(final, gm, b, cfg=base)
+                for b in range(len(cfgs))]
 
 
 def simulate_batch(gm: GraphMemory | DataflowGraph,

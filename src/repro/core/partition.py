@@ -13,8 +13,10 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import jax
 import numpy as np
 
+from .. import SPAN_CRITICALITY, SPAN_PACK_EDGES, SPAN_PACK_NODES, SPAN_SLOTS
 from .criticality import criticality as _criticality
 from .graph import DataflowGraph
 
@@ -211,54 +213,59 @@ def build_graph_memory(
                 f"placement references PEs outside the {nx}x{ny} grid")
     else:
         node_pe = place_nodes(g, num_pes, placement, seed)
-    c = _criticality(g, metric) if criticality_order else -np.arange(n, dtype=np.int64)
+    with jax.profiler.TraceAnnotation(SPAN_CRITICALITY):
+        c = (_criticality(g, metric) if criticality_order
+             else -np.arange(n, dtype=np.int64))
 
     # Local slot assignment: per PE, decreasing criticality, node id tiebreak
     # (the paper's node-labeling step — see repro.place.slots).
-    node_slot, local_counts = assign_slots(node_pe, c, num_pes)
+    with jax.profiler.TraceAnnotation(SPAN_SLOTS):
+        node_slot, local_counts = assign_slots(node_pe, c, num_pes)
 
-    lmax_nat, emax_nat = packed_shape(g, node_pe, num_pes)
-    lmax = max(lmax_nat, int(min_lmax))
-    words = max(1, math.ceil(lmax / FLAGS_PER_WORD))
-    lmax_padded = words * FLAGS_PER_WORD
+    with jax.profiler.TraceAnnotation(SPAN_PACK_NODES):
+        lmax_nat, emax_nat = packed_shape(g, node_pe, num_pes)
+        lmax = max(lmax_nat, int(min_lmax))
+        words = max(1, math.ceil(lmax / FLAGS_PER_WORD))
+        lmax_padded = words * FLAGS_PER_WORD
 
-    def per_node(arr, fill, dtype):
-        out = np.full((num_pes, lmax_padded), fill, dtype=dtype)
-        out[node_pe, node_slot] = arr
-        return out
+        def per_node(arr, fill, dtype):
+            out = np.full((num_pes, lmax_padded), fill, dtype=dtype)
+            out[node_pe, node_slot] = arr
+            return out
 
-    opcode = per_node(g.opcode, 0, np.int8)
-    fanin = per_node(g.fanin_count(), 0, np.int8)
-    init_value = per_node(g.initial_values, 0.0, np.float32)
-    valid = np.zeros((num_pes, lmax_padded), dtype=bool)
-    valid[node_pe, node_slot] = True
+        opcode = per_node(g.opcode, 0, np.int8)
+        fanin = per_node(g.fanin_count(), 0, np.int8)
+        init_value = per_node(g.initial_values, 0.0, np.float32)
+        fo_count = per_node(g.fanout_count(), 0, np.int32)
+        valid = np.zeros((num_pes, lmax_padded), dtype=bool)
+        valid[node_pe, node_slot] = True
 
     # Per-PE edge arrays: edges grouped by (pe, slot-order of source node).
-    fo_cnt_global = g.fanout_count()
-    fo_count = per_node(fo_cnt_global, 0, np.int32)
-    fo_base = np.zeros((num_pes, lmax_padded), dtype=np.int32)
-    emax = max(emax_nat, int(min_emax))
+    with jax.profiler.TraceAnnotation(SPAN_PACK_EDGES):
+        fo_base = np.zeros((num_pes, lmax_padded), dtype=np.int32)
+        emax = max(emax_nat, int(min_emax))
 
-    e_dst_pe = np.zeros((num_pes, emax), dtype=np.int32)
-    e_dst_slot = np.zeros((num_pes, emax), dtype=np.int32)
-    e_dst_opidx = np.zeros((num_pes, emax), dtype=np.int8)
+        e_dst_pe = np.zeros((num_pes, emax), dtype=np.int32)
+        e_dst_slot = np.zeros((num_pes, emax), dtype=np.int32)
+        e_dst_opidx = np.zeros((num_pes, emax), dtype=np.int8)
 
-    # Sort nodes per PE by local slot; lay their fanout lists contiguously.
-    slot_order = np.lexsort((node_slot, node_pe))
-    cursor = np.zeros(num_pes, dtype=np.int64)
-    ptr, dst, slt = g.fanout_ptr, g.fanout_dst, g.fanout_slot
-    for v in slot_order:
-        p = node_pe[v]
-        lo, hi = ptr[v], ptr[v + 1]
-        k = hi - lo
-        base = cursor[p]
-        fo_base[p, node_slot[v]] = base
-        if k:
-            d = dst[lo:hi]
-            e_dst_pe[p, base:base + k] = node_pe[d]
-            e_dst_slot[p, base:base + k] = node_slot[d]
-            e_dst_opidx[p, base:base + k] = slt[lo:hi]
-            cursor[p] = base + k
+        # Sort nodes per PE by local slot; lay their fanout lists
+        # contiguously.
+        slot_order = np.lexsort((node_slot, node_pe))
+        cursor = np.zeros(num_pes, dtype=np.int64)
+        ptr, dst, slt = g.fanout_ptr, g.fanout_dst, g.fanout_slot
+        for v in slot_order:
+            p = node_pe[v]
+            lo, hi = ptr[v], ptr[v + 1]
+            k = hi - lo
+            base = cursor[p]
+            fo_base[p, node_slot[v]] = base
+            if k:
+                d = dst[lo:hi]
+                e_dst_pe[p, base:base + k] = node_pe[d]
+                e_dst_slot[p, base:base + k] = node_slot[d]
+                e_dst_opidx[p, base:base + k] = slt[lo:hi]
+                cursor[p] = base + k
 
     return GraphMemory(
         nx=nx, ny=ny, lmax=lmax_padded, emax=emax, words=words,

@@ -23,8 +23,10 @@ from __future__ import annotations
 
 import time
 
+import jax
 import numpy as np
 
+from .. import SPAN_PLACE
 from ..core.graph import DataflowGraph
 from .anneal import anneal_placement
 from .spec import PlacementSpec
@@ -89,7 +91,8 @@ def graph_memory(g: DataflowGraph, nx: int, ny: int, placement=None, *,
 
     spec = (resolve_spec(placement)
             if not isinstance(placement, np.ndarray) else None)
-    node_pe = resolve(g, nx, ny, placement)
+    with jax.profiler.TraceAnnotation(SPAN_PLACE):
+        node_pe = resolve(g, nx, ny, placement)
     return partition.build_graph_memory(
         g, nx, ny, placement=node_pe,
         metric=metric or (spec.metric if spec else "height"),

@@ -11,11 +11,14 @@ lower the kernels as interpreted jnp, so every test forces the compiled
 path itself (``interpret=False``, or ``ops._interpret`` patched) and checks
 that a kernel (``tpu_custom_call``) is really in the compiled program.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro
 from repro.core import overlay
 from repro.core import workloads as wl
 from repro.core.overlay import OverlayConfig
@@ -96,6 +99,45 @@ def test_chunk_program_compiles(one_chip, fig1_graph, monkeypatch, engine,
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     compiled = _compile_chunk(fig1_graph, one_chip, engine)
     assert ("tpu_custom_call" in compiled.as_text()) == has_kernel
+
+
+def _computations(hlo: str) -> dict:
+    """``{name: [instruction line, ...]}`` of a compiled HLO module's text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name and line.startswith("  "):
+            comps[name].append(line)
+    return comps
+
+
+def _scopes(line: str) -> set:
+    """The device scopes in an instruction's ``op_name``, bare or under a
+    transformation (``vmap(ovl.route)``)."""
+    m = re.search(r'op_name="([^"]*)"', line)
+    parts = {re.sub(r"^\w+\((.*)\)$", r"\1", p)
+             for p in (m.group(1).split("/") if m else ())}
+    return parts & set(repro.DEVICE_SCOPES)
+
+
+def test_chunk_program_keeps_stage_scopes(one_chip, fig1_graph):
+    # Named scopes are metadata: the TPU compiler keeps them on the fusions
+    # of the chunk's while body, which is what a chip trace names ops by.
+    text = _compile_chunk(fig1_graph, one_chip, "jnp").as_text()
+    comps = _computations(text)
+    bodies = re.findall(r" while\(.*body=%([\w.-]+)", text)
+    assert bodies
+    found = set()
+    for body in bodies:
+        for line in comps[body]:
+            if " fusion(" in line:
+                found |= _scopes(line)
+    assert found == set(repro.DEVICE_SCOPES) - {repro.SCOPE_CHUNK_REPAIR}
+    assert any(repro.SCOPE_CHUNK_REPAIR in _scopes(line)
+               for lines in comps.values() for line in lines)
 
 
 def test_megakernel_is_refused(one_chip, fig1_graph, monkeypatch):
