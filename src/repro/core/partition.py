@@ -17,7 +17,7 @@ import jax
 import numpy as np
 
 from .. import SPAN_CRITICALITY, SPAN_PACK_EDGES, SPAN_PACK_NODES, SPAN_SLOTS
-from .criticality import criticality as _criticality
+from .criticality import criticality as _criticality, criticality_levels
 from .graph import DataflowGraph
 
 FLAGS_PER_WORD = 32  # paper: 32 of the 40 BRAM bits hold RDY flags
@@ -213,9 +213,14 @@ def build_graph_memory(
                 f"placement references PEs outside the {nx}x{ny} grid")
     else:
         node_pe = place_nodes(g, num_pes, placement, seed)
-    with jax.profiler.TraceAnnotation(SPAN_CRITICALITY):
-        c = (_criticality(g, metric) if criticality_order
-             else -np.arange(n, dtype=np.int64))
+    with jax.profiler.TraceAnnotation(SPAN_CRITICALITY) as span:
+        if criticality_order:
+            c, levels = criticality_levels(g, metric)
+            # The peel's step count: a graph as deep as it is large (a
+            # chain) is its worst case.
+            span.set_metadata(levels=levels)
+        else:
+            c = -np.arange(n, dtype=np.int64)
 
     # Local slot assignment: per PE, decreasing criticality, node id tiebreak
     # (the paper's node-labeling step — see repro.place.slots).
@@ -249,23 +254,26 @@ def build_graph_memory(
         e_dst_slot = np.zeros((num_pes, emax), dtype=np.int32)
         e_dst_opidx = np.zeros((num_pes, emax), dtype=np.int8)
 
-        # Sort nodes per PE by local slot; lay their fanout lists
-        # contiguously.
-        slot_order = np.lexsort((node_slot, node_pe))
-        cursor = np.zeros(num_pes, dtype=np.int64)
-        ptr, dst, slt = g.fanout_ptr, g.fanout_dst, g.fanout_slot
-        for v in slot_order:
-            p = node_pe[v]
-            lo, hi = ptr[v], ptr[v + 1]
-            k = hi - lo
-            base = cursor[p]
-            fo_base[p, node_slot[v]] = base
-            if k:
-                d = dst[lo:hi]
-                e_dst_pe[p, base:base + k] = node_pe[d]
-                e_dst_slot[p, base:base + k] = node_slot[d]
-                e_dst_opidx[p, base:base + k] = slt[lo:hi]
-                cursor[p] = base + k
+        # Each PE lays its nodes' fanout lists contiguously, in slot order:
+        # a node's base is the exclusive prefix sum of the fanout counts
+        # before it, restarted at its PE's first node.
+        fo = g.fanout_count().astype(np.int64)
+        by_slot = np.lexsort((node_slot, node_pe))
+        fo_sorted = fo[by_slot]
+        before = np.cumsum(fo_sorted) - fo_sorted
+        first = np.flatnonzero(np.diff(node_pe[by_slot], prepend=-1))
+        restart = np.zeros(n, dtype=np.int64)
+        restart[first] = before[first]
+        base = np.empty(n, dtype=np.int64)
+        base[by_slot] = before - np.maximum.accumulate(restart)
+        fo_base[node_pe, node_slot] = base
+        # Edge e of node v lands at column base[v] + (e - ptr[v]).
+        src = np.repeat(np.arange(n), fo)
+        col = base[src] + np.arange(g.num_edges) - g.fanout_ptr[src]
+        row, dst = node_pe[src], g.fanout_dst
+        e_dst_pe[row, col] = node_pe[dst]
+        e_dst_slot[row, col] = node_slot[dst]
+        e_dst_opidx[row, col] = g.fanout_slot
 
     return GraphMemory(
         nx=nx, ny=ny, lmax=lmax_padded, emax=emax, words=words,

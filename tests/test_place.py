@@ -11,7 +11,8 @@ from repro.core import workloads as wl
 from repro.core.overlay import (
     OverlayConfig, resolve_check_every, simulate, simulate_batch,
 )
-from repro.core.partition import build_graph_memory
+from repro.core.criticality import criticality
+from repro.core.partition import build_graph_memory, place_nodes
 
 ALL_POLICIES = sorted(schedulers.REGISTRY)
 
@@ -52,13 +53,90 @@ def test_explicit_array_matches_strategy_name():
 
 
 def test_assign_slots_is_the_partition_layout():
-    from repro.core.criticality import criticality
-
     gm = build_graph_memory(G, 4, 4, criticality_order=True)
     node_slot, local_counts = place.assign_slots(
         gm.node_pe, criticality(G, "height"), 16)
     np.testing.assert_array_equal(node_slot, gm.node_slot)
     np.testing.assert_array_equal(local_counts, gm.local_counts)
+
+
+def _oracle_pack(g, nx, ny, node_pe, crit, min_lmax, min_emax):
+    """The per-node loop ``build_graph_memory`` used to pack with, kept as
+    the oracle of its array code: ``{field: array}`` of a GraphMemory."""
+    num_pes = nx * ny
+    node_slot, local_counts = place.assign_slots(node_pe, crit, num_pes)
+    counts = np.bincount(node_pe, minlength=num_pes)
+    ecounts = np.bincount(node_pe, weights=g.fanout_count(),
+                          minlength=num_pes).astype(np.int64)
+    words = max(1, -(-max(int(counts.max()), min_lmax) // 32))
+    lmax = words * 32
+    emax = max(1, int(ecounts.max()), min_emax)
+
+    def per_node(arr, dtype):
+        out = np.zeros((num_pes, lmax), dtype=dtype)
+        out[node_pe, node_slot] = arr
+        return out
+
+    fo_base = np.zeros((num_pes, lmax), dtype=np.int32)
+    e_dst_pe = np.zeros((num_pes, emax), dtype=np.int32)
+    e_dst_slot = np.zeros((num_pes, emax), dtype=np.int32)
+    e_dst_opidx = np.zeros((num_pes, emax), dtype=np.int8)
+    cursor = np.zeros(num_pes, dtype=np.int64)
+    ptr, dst, slt = g.fanout_ptr, g.fanout_dst, g.fanout_slot
+    for v in np.lexsort((node_slot, node_pe)):
+        p = node_pe[v]
+        lo, hi = ptr[v], ptr[v + 1]
+        k = hi - lo
+        base = cursor[p]
+        fo_base[p, node_slot[v]] = base
+        if k:
+            d = dst[lo:hi]
+            e_dst_pe[p, base:base + k] = node_pe[d]
+            e_dst_slot[p, base:base + k] = node_slot[d]
+            e_dst_opidx[p, base:base + k] = slt[lo:hi]
+            cursor[p] = base + k
+    return dict(
+        nx=nx, ny=ny, lmax=lmax, emax=emax, words=words,
+        opcode=per_node(g.opcode, np.int8),
+        fanin=per_node(g.fanin_count(), np.int8),
+        init_value=per_node(g.initial_values, np.float32),
+        fo_base=fo_base, fo_count=per_node(g.fanout_count(), np.int32),
+        valid=per_node(True, bool),
+        e_dst_pe=e_dst_pe, e_dst_slot=e_dst_slot, e_dst_opidx=e_dst_opidx,
+        node_pe=node_pe.astype(np.int32), node_slot=node_slot,
+        local_counts=local_counts)
+
+
+#: explicit placement: every node on one of five PEs, eleven PEs left empty
+EXPLICIT = np.random.default_rng(4).integers(0, 5, G.num_nodes).astype(np.int32)
+
+PACK_VARIANTS = {
+    "plain": {},
+    "padded": {"min_lmax": 90, "min_emax": 400},
+    "id_order": {"criticality_order": False},
+    "id_order_padded": {"criticality_order": False, "min_lmax": 33,
+                        "min_emax": 1},
+}
+
+
+@pytest.mark.parametrize("variant", sorted(PACK_VARIANTS))
+@pytest.mark.parametrize("placement",
+                         ["round_robin", "random", "clustered", "explicit"])
+def test_pack_bit_identical_to_node_loop(placement, variant):
+    kw = PACK_VARIANTS[variant]
+    spec = EXPLICIT if placement == "explicit" else placement
+    gm = build_graph_memory(G, 4, 4, placement=spec, seed=3, **kw)
+    node_pe = (EXPLICIT if placement == "explicit"
+               else place_nodes(G, 16, placement, 3))
+    crit = (criticality(G, "height") if kw.get("criticality_order", True)
+            else -np.arange(G.num_nodes, dtype=np.int64))
+    want = _oracle_pack(G, 4, 4, node_pe, crit, kw.get("min_lmax", 0),
+                        kw.get("min_emax", 0))
+    for field, value in want.items():
+        got = getattr(gm, field)
+        if isinstance(value, np.ndarray):
+            assert got.dtype == value.dtype, field
+        np.testing.assert_array_equal(got, value, err_msg=field)
 
 
 def test_bad_placements_rejected():

@@ -15,6 +15,7 @@ import pytest
 import repro
 from repro.core import overlay, schedulers
 from repro.core import workloads as wl
+from repro.core.criticality import height
 from repro.core.overlay import OverlayConfig
 from repro.core.partition import build_graph_memory
 
@@ -85,6 +86,18 @@ def test_results_identical_with_profiler_open(graph, tmp_path, batched):
                     b.cycles, b.done, b.delivered, b.deflections,
                     b.busy_cycles, b.noc_deflections, b.eject_deflections)
         np.testing.assert_array_equal(a.values, b.values)
+
+
+def test_criticality_span_carries_the_peel_levels(graph, tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        build_graph_memory(graph, GRID, GRID)
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    stats = [dict(e.stats)
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name == repro.SPAN_CRITICALITY]
+    assert stats == [{"levels": int(height(graph).max()) + 1}]
 
 
 def _lowered(graph, program):
