@@ -297,6 +297,9 @@ for _name, _doc in (
      "sent around the ring again"),
     ("deflections", "noc_deflections + eject_deflections (back-compat sum)"),
     ("busy_cycles", "node fires summed over PEs and cycles"),
+    ("inject_blocked", "PE-cycles in which an active node offered a packet "
+     "and its router refused it: the PE-injection part of noc_deflections, "
+     "what a long fanout drain waits on"),
 ):
     register_counter(_name, _doc)
 
@@ -508,8 +511,9 @@ def make_cycle_fn(
             # separately. ``deflections`` stays their sum — bit-exactly the
             # pre-split stat.
             inj_blocked = inj_valid & ~accepted
+            n_blocked = inj_blocked.sum().astype(jnp.int32)
             d_noc = all_reduce(
-                inj_blocked.sum() + deflected["noc"].sum()).astype(jnp.int32)
+                n_blocked + deflected["noc"].sum()).astype(jnp.int32)
             d_ej = all_reduce(deflected["eject"].sum()).astype(jnp.int32)
 
             out = dict(
@@ -528,6 +532,7 @@ def make_cycle_fn(
                 deflections=s["deflections"] + d_noc + d_ej,
                 busy_cycles=s["busy_cycles"]
                 + all_reduce(n_fired).astype(jnp.int32),
+                inject_blocked=s["inject_blocked"] + all_reduce(n_blocked),
                 done=done,
             )
         if telem_spec is not None:
@@ -664,6 +669,8 @@ class SimResult:
     busy_cycles: int
     noc_deflections: int = 0
     eject_deflections: int = 0
+    #: PE-cycles an offered injection was refused (part of noc_deflections).
+    inject_blocked: int = 0
     #: repro.telemetry.TelemetryResult when the config carried a
     #: TelemetrySpec, else None.
     telemetry: Any = None
@@ -716,6 +723,7 @@ def _unpack_result(final, gm: GraphMemory, b: int | None = None,
         busy_cycles=int(pick(final["busy_cycles"])),
         noc_deflections=int(pick(final["noc_deflections"])),
         eject_deflections=int(pick(final["eject_deflections"])),
+        inject_blocked=int(pick(final["inject_blocked"])),
         telemetry=telemetry,
     )
 

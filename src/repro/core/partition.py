@@ -227,11 +227,12 @@ def build_graph_memory(
     with jax.profiler.TraceAnnotation(SPAN_SLOTS):
         node_slot, local_counts = assign_slots(node_pe, c, num_pes)
 
-    with jax.profiler.TraceAnnotation(SPAN_PACK_NODES):
+    with jax.profiler.TraceAnnotation(SPAN_PACK_NODES) as span:
         lmax_nat, emax_nat = packed_shape(g, node_pe, num_pes)
         lmax = max(lmax_nat, int(min_lmax))
         words = max(1, math.ceil(lmax / FLAGS_PER_WORD))
         lmax_padded = words * FLAGS_PER_WORD
+        span.set_metadata(lmax=lmax_padded)
 
         def per_node(arr, fill, dtype):
             out = np.full((num_pes, lmax_padded), fill, dtype=dtype)
@@ -246,9 +247,13 @@ def build_graph_memory(
         valid[node_pe, node_slot] = True
 
     # Per-PE edge arrays: edges grouped by (pe, slot-order of source node).
-    with jax.profiler.TraceAnnotation(SPAN_PACK_EDGES):
+    with jax.profiler.TraceAnnotation(SPAN_PACK_EDGES) as span:
         fo_base = np.zeros((num_pes, lmax_padded), dtype=np.int32)
         emax = max(emax_nat, int(min_emax))
+        # Every PE's edge arrays are padded to the busiest PE's count: under
+        # a skewed fanout emax lies far above the mean.
+        span.set_metadata(emax=emax, edges_max=emax_nat,
+                          edges_mean=g.num_edges / num_pes)
 
         e_dst_pe = np.zeros((num_pes, emax), dtype=np.int32)
         e_dst_slot = np.zeros((num_pes, emax), dtype=np.int32)

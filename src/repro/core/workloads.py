@@ -9,7 +9,9 @@ sparse LU factorization (Doolittle, no pivoting) with symbolic fill-in, where
 Every matrix entry version is a dataflow token; the DAG is exactly the data
 dependences of the factorization.
 
-Also: layered random DAGs (controllable width/fanout), reduction trees and
+Also: GAP's PageRank unrolled over a Graph500 Kronecker graph
+(:func:`kron_pagerank_graph`, power-law fanout, built from arrays), layered
+random DAGs (controllable width/fanout), reduction trees and
 chains for micro-benchmarks and property tests — plus an on-disk graph cache
 (:func:`cached_graph`) and the paper-scale :func:`fig1_full` constructor so
 benchmarks don't pay the Python DAG-elimination loop on every run.
@@ -21,7 +23,9 @@ from typing import Callable
 
 import numpy as np
 
-from .graph import OP_ADD, OP_DIV, OP_MUL, OP_SUB, DataflowGraph, GraphBuilder
+from .graph import (
+    OP_ADD, OP_DIV, OP_INPUT, OP_MUL, OP_SUB, DataflowGraph, GraphBuilder,
+)
 
 
 def _lu_eliminate(b: GraphBuilder, rows_map: list[dict[int, int]]) -> DataflowGraph:
@@ -382,6 +386,147 @@ def chain(length: int, seed: int = 0) -> DataflowGraph:
         c = b.input(rng.uniform(0.5, 2.0))
         v = b.op(OP_ADD, v, c)
     return b.build()
+
+
+#: Graph500 Kronecker initiator (D = 1 - A - B - C = 0.05).
+KRON_INITIATOR = (0.57, 0.19, 0.19)
+
+
+def kron_edges(scale: int, edge_factor: int = 16,
+               seed: int = 0) -> tuple[np.ndarray, np.ndarray]:
+    """Directed edge list ``(u, v)`` of GAP's ``kron`` graph: the Graph500
+    Kronecker generator's ``edge_factor * 2**scale`` edges, labels permuted,
+    made undirected (both directions), self loops and duplicates dropped.
+    Sorted by ``(u, v)``; vertex ids lie in ``[0, 2**scale)``.
+
+    Every bit level draws ``u``'s bit with P(1) = C + D, then ``v``'s bit
+    given ``u``'s (the Graph500 reference generator's two-step draw), all
+    from ``np.random.default_rng(seed)``; a permutation of the ``2**scale``
+    labels is drawn last.
+    """
+    a, b, c = KRON_INITIATOR
+    n = 1 << scale
+    m = edge_factor * n
+    rng = np.random.default_rng(seed)
+    src = np.zeros(m, np.int64)
+    dst = np.zeros(m, np.int64)
+    for bit in range(scale):
+        hi_u = rng.random(m) > a + b
+        hi_v = rng.random(m) > np.where(hi_u, c / (1 - a - b), a / (a + b))
+        src += hi_u.astype(np.int64) << bit
+        dst += hi_v.astype(np.int64) << bit
+    perm = rng.permutation(n)
+    src, dst = perm[src], perm[dst]
+    keep = src != dst
+    pairs = np.unique(np.concatenate([src[keep] * n + dst[keep],
+                                      dst[keep] * n + src[keep]]))
+    return pairs // n, pairs % n
+
+
+def kron_pagerank_graph(scale: int, edge_factor: int = 16,
+                        iterations: int = 2, damping: float = 0.85,
+                        seed: int = 0) -> DataflowGraph:
+    """GAP ``pr`` (pull PageRank) unrolled ``iterations`` times over the
+    ``kron`` graph of :func:`kron_edges`, as a dataflow DAG of MUL and ADD.
+
+    With ``N = 2**scale`` (GAP's vertex count, isolated vertices included)
+    and ``V`` the vertices of nonzero degree in ascending id, per iteration
+    and vertex ``v``::
+
+        contrib_v  = score_v * inv_deg_v
+        incoming_v = sum of contrib_u over v's neighbours u, ascending u,
+                     as a balanced pairwise ADD tree
+        score_v    = base_v + incoming_v * damp_v
+
+    with ``score_v = 1/N`` before the first iteration, ``inv_deg_v =
+    1/deg(v)`` shared by the iterations, and each iteration's ``damp_v =
+    damping`` and ``base_v = (1 - damping)/N`` inputs of their one
+    consumer. Isolated vertices take no part.
+
+    Node ids:
+
+    * inputs: ``score0`` (V), ``inv_deg`` (V), then per iteration ``damp``
+      (V) and ``base`` (V);
+    * per iteration: ``contrib`` (V), the ADD trees level by level (within
+      a level vertex by vertex, pair by pair: a vertex's list pairs its
+      elements 0-1, 2-3, ... and carries an odd last one to the end of the
+      next level's list), ``incoming * damp`` (V), ``score`` (V).
+
+    So the last ``V`` ids are the final scores. Edges are sorted by
+    (producer, consumer, operand slot); the left operand is slot 0.
+    """
+    n = 1 << scale
+    u, v = kron_edges(scale, edge_factor, seed)
+    verts, deg = np.unique(u, return_counts=True)
+    nv = verts.size
+    nbr = np.searchsorted(verts, v)  # (u, v) sorted: rows of u, v ascending
+
+    opcode: list[np.ndarray] = []
+    init: list[np.ndarray] = []
+    srcs: list[np.ndarray] = []
+    dsts: list[np.ndarray] = []
+    slots: list[np.ndarray] = []
+    count = 0
+
+    def inputs(value) -> np.ndarray:
+        nonlocal count
+        init.append(np.broadcast_to(np.float32(value), (nv,)).astype(np.float32))
+        opcode.append(np.full(nv, OP_INPUT, np.int8))
+        count += nv
+        return np.arange(count - nv, count)
+
+    def ops(op: int, a: np.ndarray, b: np.ndarray) -> np.ndarray:
+        nonlocal count
+        ids = np.arange(count, count + a.size)
+        count += a.size
+        opcode.append(np.full(a.size, op, np.int8))
+        init.append(np.zeros(a.size, np.float32))
+        srcs.extend((a, b))
+        dsts.extend((ids, ids))
+        slots.extend((np.zeros(a.size, np.int8), np.ones(a.size, np.int8)))
+        return ids
+
+    score = inputs(1.0 / n)
+    inv_deg = inputs(1.0 / deg)
+    damp = [inputs(damping) for _ in range(iterations)]
+    base = [inputs((1.0 - damping) / n) for _ in range(iterations)]
+    for t in range(iterations):
+        contrib = ops(OP_MUL, score, inv_deg)
+        cur, lens = contrib[nbr], deg.copy()
+        while (lens > 1).any():
+            pos = np.arange(cur.size) - np.repeat(np.cumsum(lens) - lens, lens)
+            has_pair = (pos % 2 == 0) & (np.repeat(lens, lens) > pos + 1)
+            left = np.flatnonzero(has_pair)
+            merged = cur.copy()
+            merged[left] = ops(OP_ADD, cur[left], cur[left + 1])
+            cur, lens = merged[pos % 2 == 0], (lens + 1) // 2
+        score = ops(OP_ADD, base[t], ops(OP_MUL, cur, damp[t]))
+
+    src, dst, slot = (np.concatenate(x) for x in (srcs, dsts, slots))
+    order = np.lexsort((slot, dst, src))
+    return DataflowGraph(
+        opcode=np.concatenate(opcode),
+        fanout_ptr=np.concatenate(
+            [[0], np.cumsum(np.bincount(src, minlength=count))]),
+        fanout_dst=dst[order],
+        fanout_slot=slot[order],
+        initial_values=np.concatenate(init),
+    )
+
+
+def pagerank_reference(u: np.ndarray, v: np.ndarray, n: int,
+                       iterations: int = 2,
+                       damping: float = 0.85) -> np.ndarray:
+    """GAP's pull PageRank in float64 over the directed edges ``u -> v`` of
+    an ``n``-vertex graph: [n] scores after ``iterations``, from 1/n."""
+    deg = np.bincount(u, minlength=n)
+    scores = np.full(n, 1.0 / n)
+    for _ in range(iterations):
+        contrib = np.divide(scores, deg, out=np.zeros(n), where=deg > 0)
+        incoming = np.zeros(n)
+        np.add.at(incoming, v, contrib[u])
+        scores = (1.0 - damping) / n + damping * incoming
+    return scores
 
 
 def random_dag(num_nodes: int, seed: int = 0, input_frac: float = 0.2) -> DataflowGraph:

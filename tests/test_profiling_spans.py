@@ -100,6 +100,24 @@ def test_criticality_span_carries_the_peel_levels(graph, tmp_path):
     assert stats == [{"levels": int(height(graph).max()) + 1}]
 
 
+def test_pack_spans_carry_the_padded_shapes(tmp_path):
+    g = wl.kron_pagerank_graph(6, seed=1)
+    with jax.profiler.trace(str(tmp_path)):
+        gm = build_graph_memory(g, GRID, GRID)
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    stats = {e.name: dict(e.stats)
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name in (repro.SPAN_PACK_NODES, repro.SPAN_PACK_EDGES)}
+    per_pe = np.bincount(gm.node_pe, weights=g.fanout_count(),
+                         minlength=GRID * GRID)
+    assert stats[repro.SPAN_PACK_NODES] == {"lmax": gm.lmax}
+    edges = stats[repro.SPAN_PACK_EDGES]
+    assert edges["emax"] == gm.emax == edges["edges_max"] == per_pe.max()
+    assert edges["edges_mean"] == pytest.approx(per_pe.mean())
+
+
 def _lowered(graph, program):
     gm = build_graph_memory(graph, GRID, GRID)
     g = dict(overlay.device_graph(gm))
