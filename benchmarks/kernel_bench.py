@@ -50,12 +50,10 @@ def _policy_state(policy, nx, ny, words, rng, fill_rounds=16):
         valid=jnp.ones((nx, ny, L), bool),
     )
     st = policy.init(g, OverlayConfig(scheduler=policy.name))
-    ix = jnp.arange(nx)[:, None] * jnp.ones((1, ny), jnp.int32)
-    iy = jnp.arange(ny)[None, :] * jnp.ones((nx, 1), jnp.int32)
     for _ in range(fill_rounds):
         slot = jnp.asarray(rng.integers(0, L, size=(nx, ny), dtype=np.int32))
         ready = jnp.asarray(rng.random(size=(nx, ny)) < 0.75)
-        st = policy.on_ready(st, ix, iy, slot, ready)
+        st = policy.on_ready(st, slot, ready)
     return jax.tree.map(jnp.asarray, st)
 
 

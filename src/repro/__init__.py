@@ -39,7 +39,7 @@ PACK_SPANS = (SPAN_PLACE, SPAN_CRITICALITY, SPAN_SLOTS, SPAN_PACK_NODES,
 
 SCOPE_INJECT = "ovl.inject"             # cycle stage 1: injection offer
 SCOPE_ROUTE = "ovl.route"               # stages 2-3: Hoplite route, cursor
-SCOPE_EJECT_FIRE = "ovl.eject_fire"     # stage 4: eject scatter, fire, ALU
+SCOPE_EJECT_FIRE = "ovl.eject_fire"     # stage 4: eject updates, fire, ALU
 SCOPE_SELECT = "ovl.select"             # stage 5: scheduler pick
 SCOPE_TERMINATE = "ovl.terminate"       # stage 6: done predicate, stats
 SCOPE_CHUNK_REPAIR = "ovl.chunk_repair"  # a chunk's cycle and stat repair

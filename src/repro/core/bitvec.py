@@ -25,17 +25,35 @@ def slot_word_mask(slot):
     return word, (_U32(1) << bitpos)
 
 
-def set_bit(bits, pe, slot, on):
-    """Set/clear one bit per PE row. bits: [..., P, W]; pe/slot/on: [..., P]."""
+def slot_bits(slot, W):
+    """[...] slot -> [..., W] uint32 row holding only the slot's flag.
+
+    Setting or clearing one flag per row ORs this row in or masks it out:
+    one dense pass over the row, which on a TPU costs less than a scatter
+    of one word per row (a scatter's cost is per index, not per byte).
+    """
     word, mask = slot_word_mask(slot)
-    row = bits[..., pe, word]
-    new = jnp.where(on, row | mask, row)
-    return bits.at[..., pe, word].set(new)
+    hit = jnp.arange(W, dtype=jnp.int32) == word[..., None]
+    return jnp.where(hit, mask[..., None], _U32(0))
 
 
-def test_bit(bits, pe, slot):
-    word, mask = slot_word_mask(slot)
-    return (bits[..., pe, word] & mask) != 0
+def set_bit(bits, slot, on):
+    """Set flag ``slot`` of each row where ``on``. bits: [..., W];
+    slot/on: [...]."""
+    flag = slot_bits(slot, bits.shape[-1])
+    return bits | jnp.where(on[..., None], flag, _U32(0))
+
+
+def clear_bit(bits, slot, off):
+    """Clear flag ``slot`` of each row where ``off``. bits: [..., W];
+    slot/off: [...]."""
+    flag = slot_bits(slot, bits.shape[-1])
+    return bits & ~jnp.where(off[..., None], flag, _U32(0))
+
+
+def test_bit(bits, slot):
+    """Whether flag ``slot`` of each row is set. bits: [..., W]; slot: [...]."""
+    return (bits & slot_bits(slot, bits.shape[-1])).any(axis=-1)
 
 
 def smear(w):

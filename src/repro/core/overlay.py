@@ -24,10 +24,11 @@ Pallas kernels in :mod:`repro.kernels.lod` via
 ``inorder``, ``scan``, ``lru_flat``) and how to add one.
 
 Hot-path engineering (engine-level, never observable in results):
-  * *Fused eject application*: every eject port applies as one stacked
-    scatter per state array and fire detection stays in gathered per-port
-    form, so a cycle costs O(PEs), not O(PEs x slots); termination tracks a
-    remaining-nodes counter instead of reducing the computed plane.
+  * *Fused eject application*: each eject port writes its target slot of
+    every PE by one one-hot masked dense pass per state array, never a
+    scatter (see ``_deliver``), and fire detection stays in gathered
+    per-port form; termination tracks a remaining-nodes counter instead of
+    reducing the computed plane.
   * *Chunked stepping* (``OverlayConfig.check_every``; autotuned 8-32 from
     graph size, ``1`` = the per-cycle reference engine): ``check_every``
     cycles run back-to-back in a ``lax.scan`` per ``while_loop`` iteration,
@@ -347,6 +348,42 @@ def init_state(g: DeviceGraph, cfg: OverlayConfig,
     return state
 
 
+def _deliver(operands, pending, valid, slot, opidx, val):
+    """Apply the eject ports' packets: each adds its value into its
+    (slot, opidx) operand cell and counts its slot's pending operands down.
+
+    Port arrays are [E, nx, ny], ``slot`` and ``opidx`` already clipped.
+    Each port writes by one masked dense pass over the slot axis, one-hot at
+    its slot, in port order: on a TPU the loop state sits in VMEM and a pass
+    over a whole [nx, ny, L] array costs less than a scatter of one element
+    per PE, whose cost is per index. The cells written are exactly a point
+    update's: a port with no packet adds zero to its slot, and every other
+    cell is left as it was.
+    """
+    slots = jnp.arange(pending.shape[-1], dtype=jnp.int32)
+    sides = jnp.arange(2, dtype=jnp.int32)
+    for e in range(valid.shape[0]):
+        hit = slots == slot[e][..., None]
+        cell = hit[..., None] & (sides == opidx[e][..., None, None])
+        add = jnp.where(valid[e], val[e], 0.0)[..., None, None]
+        operands = jnp.where(cell, operands + add, operands)
+        drop = valid[e].astype(jnp.int32)[..., None]
+        pending = jnp.where(hit, pending - drop, pending)
+    return operands, pending
+
+
+def _record_fires(value, computed, slot, fired, fval):
+    """Add each port's fired value into its slot and mark the slot computed,
+    by the same one-hot dense passes as :func:`_deliver`."""
+    slots = jnp.arange(value.shape[-1], dtype=jnp.int32)
+    for e in range(slot.shape[0]):
+        hit = slots == slot[e][..., None]
+        add = jnp.where(fired[e], fval[e], 0.0)[..., None]
+        value = jnp.where(hit, value + add, value)
+        computed = computed | (hit & fired[e][..., None])
+    return value, computed
+
+
 def make_cycle_fn(
     g: DeviceGraph,
     cfg: OverlayConfig,
@@ -364,7 +401,7 @@ def make_cycle_fn(
     ``x0``/``y0``/``global_ny`` supply global router coordinates when the PE
     grid is sharded (see core.distributed)."""
     sched = _resolve(cfg, scheduler)
-    nx, ny, L = g["opcode"].shape
+    _, ny, L = g["opcode"].shape
     ny_i32 = jnp.int32(global_ny if global_ny is not None else ny)
     telem_spec = cfg.telemetry
     if telem_spec is not None:
@@ -402,19 +439,15 @@ def make_cycle_fn(
             sel_wait = jnp.where(drained, s["sel_lat"] - 1, s["sel_wait"])
 
         with jax.named_scope(SCOPE_EJECT_FIRE):
-            # ---- 4. apply ejected packets, fused across the eject ports.
-            # All eject ports apply as ONE stacked [E, nx, ny] scatter per
-            # array instead of ``eject_capacity`` sequential full-grid
-            # gather/scatter rounds, and fire detection stays in gathered
-            # [E, nx, ny] form, so the per-cycle cost is O(PEs), not
-            # O(PEs x slots). Order-free exactness relies on the graph-memory
-            # invariants that each (pe, slot, opidx) operand cell receives
-            # exactly one packet over the whole run (fanin semantics) and
-            # each slot fires at most once, so scatter-*add* into the
-            # zero-initialized cells equals the sequential writes of the
-            # per-port loop this replaces.
-            ix = jnp.arange(nx)[:, None] * jnp.ones((1, ny), jnp.int32)
-            iy = jnp.arange(ny)[None, :] * jnp.ones((nx, 1), jnp.int32)
+            # ---- 4. apply ejected packets, fused across the eject ports,
+            # then fire. Every write lands in the one slot a port targets
+            # on each PE (see _deliver), and fire detection stays in
+            # gathered [E, nx, ny] form. Exactness relies on the
+            # graph-memory invariants that each (pe, slot, opidx) operand
+            # cell receives exactly one packet over the whole run (fanin
+            # semantics) and each slot fires at most once, so adding into
+            # the zero-initialized cells equals the sequential writes of a
+            # per-port loop.
             sched_st = s["sched"]
 
             ej_valid = jnp.stack([e["valid"] for e in ejects])  # [E,nx,ny]
@@ -422,51 +455,39 @@ def make_cycle_fn(
                                0, L - 1)
             ej_op = jnp.clip(jnp.stack([e["opidx"] for e in ejects]), 0, 1)
             ej_val = jnp.stack([e["value"] for e in ejects])
-
-            # With one eject port the (pe, slot) scatter indices are unique
-            # and iterate in row-major order — tell XLA so it takes the fast
-            # path.
             E = len(ejects)
-            hints = dict(mode="promise_in_bounds",
-                         unique_indices=E == 1, indices_are_sorted=E == 1)
-            operands = s["operands"].at[
-                ix[None], iy[None], ej_slot, ej_op].add(
-                    jnp.where(ej_valid, ej_val, 0.0), **hints)
-            pending = s["pending"].at[ix[None], iy[None], ej_slot].add(
-                -ej_valid.astype(jnp.int32), **hints)
+            operands, pending = _deliver(s["operands"], s["pending"],
+                                         ej_valid, ej_slot, ej_op, ej_val)
 
             # A slot fires the cycle a delivery drops its pending count to
             # zero. Gathered at each port's own target slot; when two ports
             # hit the same slot in one cycle both see the post-decrement
             # count, so the first port claims the fire (same single fire,
             # same operands and value, as the sequential loop).
-            new_pend = jnp.stack([_row_gather(pending, ej_slot[e])
-                                  for e in range(E)])
-            was_done = jnp.stack([_row_gather(s["computed"], ej_slot[e])
-                                  for e in range(E)])
-            fired = ej_valid & (new_pend == 0) & ~was_done
-            for e in range(1, E):
+            new_pend = [_row_gather(pending, ej_slot[e]) for e in range(E)]
+            fired = []
+            for e in range(E):
+                f = (ej_valid[e] & (new_pend[e] == 0)
+                     & ~_row_gather(s["computed"], ej_slot[e]))
                 for prev in range(e):
-                    dup = fired[prev] & (ej_slot[prev] == ej_slot[e])
-                    fired = fired.at[e].set(fired[e] & ~dup)
+                    f = f & ~(fired[prev] & (ej_slot[prev] == ej_slot[e]))
+                fired.append(f)
+            fired = jnp.stack(fired)
 
             opnds = jnp.stack([_row_gather(operands, ej_slot[e])
                                for e in range(E)])  # [E,nx,ny,2]
             opc = jnp.stack([_row_gather(g["opcode"], ej_slot[e])
                              for e in range(E)])
             fval = alu(opc, opnds[..., 0], opnds[..., 1])
-            value = s["value"].at[ix[None], iy[None], ej_slot].add(
-                jnp.where(fired, fval, 0.0), **hints)
-            computed = s["computed"].at[ix[None], iy[None], ej_slot].max(
-                fired, mode="promise_in_bounds")
+            value, computed = _record_fires(s["value"], s["computed"],
+                                            ej_slot, fired, fval)
 
             # Enqueue fired nodes in eject-port order (per-PE FIFO arrival
             # semantics are exactly the sequential loop's).
             for e in range(E):
                 ready_e = fired[e] & (
                     _row_gather(g["fo_count"], ej_slot[e]) > 0)
-                sched_st = sched.on_ready(sched_st, ix, iy, ej_slot[e],
-                                          ready_e)
+                sched_st = sched.on_ready(sched_st, ej_slot[e], ready_e)
 
             n_delivered = ej_valid.sum().astype(jnp.int32)
             n_fired = fired.sum().astype(jnp.int32)

@@ -7,7 +7,7 @@ policy behind a small protocol so the cycle kernel in
 :mod:`repro.core.overlay` stays policy-agnostic:
 
   * ``init(g, cfg)``                      -> per-PE scheduler state pytree
-  * ``on_ready(st, ix, iy, slot, ready)`` -> mark ``slot`` ready where ``ready``
+  * ``on_ready(st, slot, ready)``         -> mark ``slot`` ready where ``ready``
   * ``select(st, idle)``                  -> (candidate slot, have) per PE
   * ``commit(st, sel, cand)``             -> consume the candidate where ``sel``
   * ``empty(st)``                         -> scalar bool: no node is queued
@@ -27,7 +27,9 @@ this protocol included — inside one Pallas kernel (see docs/megakernel.md).
 All hooks are pure jnp functions of [nx, ny, ...] arrays, so every policy
 works unchanged under ``jax.jit``, ``shard_map`` (state is local to a PE row)
 and ``jax.vmap`` (the batched sweep engine, see
-:func:`repro.core.overlay.simulate_batch`).
+:func:`repro.core.overlay.simulate_batch`). A hook that writes one entry per
+PE (an RDY flag, a FIFO cell) does it by a masked dense pass over the PE's
+row, one-hot at the entry, never by a scatter (see :func:`bitvec.slot_bits`).
 
 Registered policies:
 
@@ -82,25 +84,10 @@ def _rdy_image(need_drain):
         masks.reshape(nx, ny, W, 32), jnp.uint32(0), jax.lax.bitwise_or, (3,))
 
 
-def _set_rdy_bit(rdy, ix, iy, slot, on):
-    nx, ny, _ = rdy.shape
-    return bitvec.set_bit(
-        rdy.reshape(nx * ny, -1),
-        (ix * ny + iy).reshape(-1),
-        slot.reshape(-1),
-        on.reshape(-1),
-    ).reshape(nx, ny, -1)
-
-
 def _clear_selected(rdy, sel, cand):
     """Clear bit ``cand`` on PEs where ``sel``; L = W * 32."""
-    nx, ny, W = rdy.shape
-    L = W * bitvec.FLAGS_PER_WORD
-    ix = jnp.arange(nx)[:, None] * jnp.ones((1, ny), jnp.int32)
-    iy = jnp.arange(ny)[None, :] * jnp.ones((nx, 1), jnp.int32)
-    word, mask = bitvec.slot_word_mask(jnp.clip(cand, 0, L - 1))
-    row = rdy[ix, iy, word]
-    return rdy.at[ix, iy, word].set(jnp.where(sel, row & ~mask, row))
+    L = rdy.shape[-1] * bitvec.FLAGS_PER_WORD
+    return bitvec.clear_bit(rdy, jnp.clip(cand, 0, L - 1), sel)
 
 
 class Scheduler:
@@ -118,7 +105,7 @@ class Scheduler:
     def init(self, g, cfg) -> dict:
         raise NotImplementedError
 
-    def on_ready(self, st: dict, ix, iy, slot, ready) -> dict:
+    def on_ready(self, st: dict, slot, ready) -> dict:
         raise NotImplementedError
 
     def select(self, st: dict, idle):
@@ -182,8 +169,8 @@ class OooScheduler(Scheduler):
     def init(self, g, cfg):
         return dict(rdy=_rdy_image(_initial_ready(g)))
 
-    def on_ready(self, st, ix, iy, slot, ready):
-        return dict(st, rdy=_set_rdy_bit(st["rdy"], ix, iy, slot, ready))
+    def on_ready(self, st, slot, ready):
+        return dict(st, rdy=bitvec.set_bit(st["rdy"], slot, ready))
 
     def select(self, st, idle):
         cand = bitvec.leading_one(st["rdy"])   # most critical ready slot
@@ -232,12 +219,13 @@ class InorderScheduler(Scheduler):
             size=need_drain.sum(axis=-1).astype(jnp.int32),
         )
 
-    def on_ready(self, st, ix, iy, slot, ready):
+    def on_ready(self, st, slot, ready):
         fifo, head, size = st["fifo"], st["head"], st["size"]
         depth = fifo.shape[-1]
         tail = (head + size) % depth
-        old = fifo[ix, iy, tail]
-        fifo = fifo.at[ix, iy, tail].set(jnp.where(ready, slot, old))
+        push = (jnp.arange(depth, dtype=jnp.int32) == tail[..., None]) \
+            & ready[..., None]
+        fifo = jnp.where(push, slot[..., None], fifo)
         return dict(fifo=fifo, head=head, size=size + ready.astype(jnp.int32))
 
     def select(self, st, idle):
@@ -271,8 +259,8 @@ class _RotatingRdyScheduler(Scheduler):
         return dict(rdy=_rdy_image(_initial_ready(g)),
                     ptr=jnp.zeros((nx, ny), jnp.int32))
 
-    def on_ready(self, st, ix, iy, slot, ready):
-        return dict(st, rdy=_set_rdy_bit(st["rdy"], ix, iy, slot, ready))
+    def on_ready(self, st, slot, ready):
+        return dict(st, rdy=bitvec.set_bit(st["rdy"], slot, ready))
 
     def select(self, st, idle):
         rdy = st["rdy"]
@@ -372,10 +360,10 @@ class BatchedScheduler(Scheduler):
         masking and ``jnp.select`` dispatch are pruned at trace time."""
         return len(self.policies) == 1
 
-    def on_ready(self, st, ix, iy, slot, ready):
+    def on_ready(self, st, slot, ready):
         out = dict(st)
         for n, p in zip(self.names, self.policies):
-            out[n] = p.on_ready(st[n], ix, iy, slot, ready)
+            out[n] = p.on_ready(st[n], slot, ready)
         return out
 
     def select(self, st, idle):

@@ -10,7 +10,7 @@ from HBM. This module fuses the *entire chunk* instead: one
 cycles — scheduler select for every registered policy (via the
 ``Scheduler.step`` protocol, same code path the PR-2 ``schedule_step`` /
 ``rotating_schedule_step`` kernels generalized), the unidirectional-Hoplite
-torus route, the fused per-port eject scatters, and the remaining-nodes
+torus route, the fused per-port eject updates, and the remaining-nodes
 termination counter — with operand/dependency state carried across cycles in
 kernel refs (VMEM on TPU) rather than round-tripped per dispatch.
 

@@ -140,6 +140,38 @@ def test_chunk_program_keeps_stage_scopes(one_chip, fig1_graph):
                for lines in comps.values() for line in lines)
 
 
+def _reachable(comps: dict, roots) -> set:
+    """The computations ``roots`` call, directly or through others: fused
+    computations, reducers, nested loop bodies."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.-]+)", line)
+    return seen
+
+
+@pytest.mark.parametrize("engine", ["jnp", "select"])
+def test_chunk_loop_has_no_scatter(one_chip, fig1_graph, monkeypatch,
+                                   engine):
+    # The cycle body writes one slot, RDY word or FIFO cell per PE by a
+    # one-hot dense pass: a point scatter costs per index on a TPU, and
+    # six of them took most of a cycle's device time.
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    text = _compile_chunk(fig1_graph, one_chip, engine).as_text()
+    comps = _computations(text)
+    bodies = re.findall(r" while\(.*body=%([\w.-]+)", text)
+    assert bodies
+    scatters = [line.strip()[:120]
+                for name in _reachable(comps, bodies)
+                for line in comps[name] if re.search(r" scatter\(", line)]
+    assert not scatters
+
+
 def test_megakernel_is_refused(one_chip, fig1_graph, monkeypatch):
     # The per-PE [nx, ny, L] gathers of the cycle body, traced into one
     # kernel, are more than the TPU kernel compiler lowers. Once this
