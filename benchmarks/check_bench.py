@@ -58,16 +58,15 @@ def _cycle_counts(bench: dict) -> dict[str, int]:
     """Flatten every tracked cycle count to {metric_name: cycles}."""
     out: dict[str, int] = {}
     flat_rows = list(bench.get("fig1", []))
-    # Placement / eject / surrogate / guided / fig1_full / megakernel
-    # sections carry per-row cycles_* keys like fig1 does (identity/random/
-    # annealed placements; n_first/priority arbitration; multilevel and
-    # guided searches; the fig1-full tracked row; the fused-chunk engine's
-    # bit-exactness rows; the telemetry-on runs, whose cycles must equal the
-    # untraced baseline) — all deterministic simulation semantics, all
-    # blocking. (jnp_cycles_per_sec / cycles_per_sec are throughput and stay
-    # informational: only the cycles_ prefix is gated.)
+    # Placement / eject / surrogate / guided / fig1_full sections carry
+    # per-row cycles_* keys like fig1 does (identity/random/annealed
+    # placements; n_first/priority arbitration; multilevel and guided
+    # searches; the fig1-full tracked row; the telemetry-on runs, whose
+    # cycles must equal the untraced baseline) — all deterministic
+    # simulation semantics, all blocking. (cycles_per_sec is throughput and
+    # stays informational: only the cycles_ prefix is gated.)
     for section in ("placement", "eject", "surrogate", "guided", "fig1_full",
-                    "megakernel", "telemetry", "service"):
+                    "telemetry", "service"):
         flat_rows += bench.get(section, {}).get("rows", [])
     for row in flat_rows:
         for key, val in row.items():
@@ -217,13 +216,12 @@ def _wall_times(bench: dict) -> dict[str, float]:
     out: dict[str, float] = {}
     rows = list(bench.get("fig1", []))
     for section in ("placement", "eject", "surrogate", "guided", "fig1_full",
-                    "megakernel", "telemetry", "service"):
+                    "telemetry", "service"):
         rows += bench.get(section, {}).get("rows", [])
     for row in rows:
         out[f"{row['name']}.wall_s"] = float(row["wall_s"])
-        for key in ("cycles_per_sec", "jnp_cycles_per_sec"):
-            if key in row:
-                out[f"{row['name']}.{key}"] = float(row[key])
+        if "cycles_per_sec" in row:
+            out[f"{row['name']}.cycles_per_sec"] = float(row["cycles_per_sec"])
     return out
 
 

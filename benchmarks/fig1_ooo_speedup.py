@@ -36,7 +36,7 @@ DEFAULT_POLICIES = ("ooo", "inorder")
 
 
 def _run_policies(g, nx, ny, policies, max_cycles=8_000_000, timed=False,
-                  check_every=None, engine="jnp"):
+                  check_every=None):
     """One batched program per GraphMemory layout group. Returns
     ({policy: cycles}, wall seconds[, hot wall seconds]).
 
@@ -54,7 +54,7 @@ def _run_policies(g, nx, ny, policies, max_cycles=8_000_000, timed=False,
     for wants, group in groups.items():
         gm = build_graph_memory(g, nx, ny, criticality_order=wants)
         cfgs = [OverlayConfig(scheduler=p, max_cycles=max_cycles,
-                              check_every=check_every, engine=engine)
+                              check_every=check_every)
                 for p in group]
         for p, r in zip(group, overlay_run(gm, batch=cfgs)):
             assert r.done, p
@@ -134,43 +134,6 @@ def chunking_throughput(nx: int = 16, ny: int = 16,
         "auto_check_every": k,
         "speedup_hot": round(auto["cycles_per_sec"] / base["cycles_per_sec"], 4),
     }
-
-
-def megakernel_rows(nx: int = 16, ny: int = 16):
-    """Fused megakernel engine vs the jnp reference on the small fig1
-    graphs: cycle counts must be bit-identical (CI-gated via the cycles_*
-    keys), the jnp-vs-fused ``cycles_per_sec`` pair is informational
-    (min-over-reps hot timing, interpret mode on CPU — the compiled-TPU
-    rates are the open follow-up). Graphs come from the on-disk cache
-    (``workloads.MEGAKERNEL_BENCH_GRAPHS``), pre-warmed by CI."""
-    rows = []
-    for name in wl.MEGAKERNEL_BENCH_GRAPHS:
-        parts = dict((p[0], int(p[1:])) for p in name.split("_")[1:]
-                     if p[0] in "bsw" and p[1:].isdigit())
-        g = wl.cached_graph(name, lambda b=parts["b"], s=parts["s"],
-                            w=parts["w"]: wl.arrow_lu_graph(b, s, w, seed=3))
-        cyc_jnp, _, hot_jnp = _run_policies(g, nx, ny, ("ooo", "inorder"),
-                                            timed=True)
-        cyc_mega, wall, hot_mega = _run_policies(
-            g, nx, ny, ("ooo", "inorder"), timed=True, engine="megakernel")
-        assert cyc_mega == cyc_jnp, (name, cyc_mega, cyc_jnp)
-        total = sum(cyc_mega.values())
-        row = {
-            "name": f"megakernel_arrow_n{g.num_nodes}",
-            "us_per_call": round(1e6 * hot_mega, 1),
-            # fused-vs-jnp hot speedup (>1 means the megakernel wins)
-            "derived": round(hot_jnp / hot_mega, 4),
-            "nodes": g.num_nodes,
-            "edges": g.num_edges,
-            "wall_s": round(wall, 3),
-            "hot_wall_s": round(hot_mega, 3),
-            "hot_wall_s_jnp": round(hot_jnp, 3),
-            "cycles_per_sec": round(total / hot_mega, 1),
-            "jnp_cycles_per_sec": round(total / hot_jnp, 1),
-        }
-        row.update({f"cycles_{p}": c for p, c in sorted(cyc_mega.items())})
-        rows.append(row)
-    return rows
 
 
 def sweep_policies(nx: int = 16, ny: int = 16,

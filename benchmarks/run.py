@@ -8,8 +8,6 @@
               hot-timed simulated-cycles-per-second throughput per row
   sweep     — every registered policy on one graph via one batched program
   chunking  — chunked-engine throughput: check_every=1 vs autotuned depth
-  megakernel— fused single-pallas_call chunk engine vs the jnp reference
-              (cycle counts CI-gated bit-exact; throughput informational)
   placement — repro.place subsystem: identity vs random vs annealed
               placements (CI-gated cycles) + priority eject arbitration
   guided    — surrogate-guided annealing vs the plain annealer: cycles and
@@ -92,14 +90,6 @@ def main() -> None:
         print(f"{r['name']},{r['us_per_call']},{r['derived']}", flush=True)
     print(f"chunking_speedup_hot,0.0,{bench['chunking']['speedup_hot']}",
           flush=True)
-
-    # Megakernel engine: the fused single-pallas_call chunk vs the jnp
-    # reference on the small fig1 graphs — cycle counts bit-exact (CI-gated),
-    # the jnp-vs-fused cycles_per_sec pair informational (min-over-reps hot
-    # timing; interpret mode on CPU runners).
-    bench["megakernel"] = {"rows": fig1_ooo_speedup.megakernel_rows()}
-    for r in bench["megakernel"]["rows"]:
-        print(f"{r['name']},{r['us_per_call']},{r['derived']}", flush=True)
 
     # Placement subsystem: identity vs random vs NoC-annealed placements
     # (cycle counts CI-gated), and the criticality-aware eject arbitration

@@ -28,7 +28,7 @@ from repro.telemetry import TelemetrySpec
 
 
 def run(nx: int = 16, ny: int = 16):
-    name = wl.MEGAKERNEL_BENCH_GRAPHS[0]
+    name = "arrow_b4_s10_w8_seed3"
     g = wl.cached_graph(name, lambda: wl.arrow_lu_graph(4, 10, 8, seed=3))
     spec = TelemetrySpec()
     rows = []

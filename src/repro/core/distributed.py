@@ -53,26 +53,8 @@ def _shard_shift(axis_name: str, axis_idx: int, n: int):
     return shift
 
 
-def _mk_chunk(gl, cfg, K, sched, mk_cycle, all_reduce, nsx, nsy, *, batched):
-    """Per-shard chunk step honoring ``cfg.engine``.
-
-    The megakernel chunk (one fused ``pallas_call`` per K cycles) cannot
-    contain the cross-shard ppermute a sharded torus shift needs, so it runs
-    only when both mesh axes are size 1 — the shifts are then pure local
-    rolls and the shard-local grid IS the global grid (x0 = y0 = 0). On a
-    real multi-shard mesh it raises rather than run some other engine than
-    the one asked for (docs/megakernel.md, "Mesh semantics")."""
-    if cfg.engine == "megakernel":
-        if nsx != 1 or nsy != 1:
-            raise ValueError(
-                f"engine='megakernel' cannot run on a multi-shard mesh "
-                f"({nsx}x{nsy} shards): the fused kernel cannot hold the "
-                f"cross-shard torus exchange; use engine='jnp' or 'select'")
-        from ..kernels import megakernel
-
-        return megakernel.make_mega_chunk_fn(
-            gl, cfg, K, scheduler=sched, batched=batched,
-            all_reduce=all_reduce)
+def _mk_chunk(K, mk_cycle, all_reduce, *, batched):
+    """Per-shard chunk step: K shard-local cycles, one reduction per chunk."""
     chunk = overlay.make_chunk_fn(mk_cycle(lambda x: x), K, all_reduce)
     return jax.vmap(chunk) if batched else chunk
 
@@ -160,12 +142,11 @@ def _simulate_sharded(gm: GraphMemory, mesh: Mesh,
         def cond(s):
             return (~s["done"]) & (s["cycle"] < cfg.max_cycles)
 
-        if K > 1 or cfg.engine == "megakernel":
+        if K > 1:
             # Guard-free chunks while a whole chunk fits the budget; the
             # per-cycle engine (with its per-cycle collectives) only runs
             # the < K tail cycles.
-            chunk = _mk_chunk(gl, cfg, K, sched, mk_cycle, all_reduce,
-                              nsx, nsy, batched=False)
+            chunk = _mk_chunk(K, mk_cycle, all_reduce, batched=False)
             state = jax.lax.while_loop(
                 lambda s: (~s["done"]) & (s["cycle"] + K <= cfg.max_cycles),
                 chunk, state)
@@ -232,7 +213,7 @@ def _simulate_batch_sharded(gm: GraphMemory, mesh: Mesh,
     if len(engines) != 1:
         raise ValueError(
             f"simulate_batch_sharded needs a uniform engine "
-            f"('jnp' | 'select' | 'megakernel'), got {engines}")
+            f"('jnp' | 'select'), got {engines}")
     placements = {c.placement for c in cfgs}
     if len(placements) != 1:
         raise ValueError(
@@ -314,9 +295,8 @@ def _simulate_batch_sharded(gm: GraphMemory, mesh: Mesh,
         def cond(s):
             return ((~s["done"]) & (s["cycle"] < max_cycs)).any()
 
-        if K > 1 or base.engine == "megakernel":
-            vchunk = _mk_chunk(gl, base, K, sched, mk_cycle, all_reduce,
-                               nsx, nsy, batched=True)
+        if K > 1:
+            vchunk = _mk_chunk(K, mk_cycle, all_reduce, batched=True)
 
             def chunk_cond(s):
                 running = (~s["done"]) & (s["cycle"] < max_cycs)

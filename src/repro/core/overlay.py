@@ -37,10 +37,6 @@ Hot-path engineering (engine-level, never observable in results):
     the cycle body, so the exact completion cycle is recovered from the
     chunk's per-cycle done trace (see :func:`make_chunk_fn`); results are
     bit-identical for every ``check_every``.
-  * *Megakernel chunks* (``OverlayConfig(engine="megakernel")``): the whole
-    chunk fuses into ONE ``pallas_call`` with state carried across its K
-    cycles in kernel refs (:mod:`repro.kernels.megakernel`); the jnp scan
-    above stays the bit-exact reference oracle.
 
 Profiling: the cycle body's stages run under ``jax.named_scope``s
 (``ovl.inject``, ``ovl.route``, ``ovl.eject_fire``, ``ovl.select``,
@@ -49,7 +45,7 @@ only name the compiled ops, and the host steps of an engine call under
 ``jax.profiler.TraceAnnotation`` spans (``repro.pack``, ``repro.to_device``,
 ``repro.dispatch``, ``repro.unpack``). The names live in :mod:`repro`.
 
-Three execution engines share the same cycle body:
+Three entry points share the same cycle body:
   * :func:`simulate`          — single device, one config;
   * :func:`simulate_batch`    — one device, a *stacked* config axis: the body
     is vmapped so an N-scheduler x M-latency sweep is one XLA program
@@ -82,7 +78,7 @@ from .schedulers import row_gather as _row_gather
 Shift = Callable[[dict], dict]
 
 #: Chunk execution engines (cycle-exact by construction, see OverlayConfig).
-ENGINES = ("jnp", "select", "megakernel")
+ENGINES = ("jnp", "select")
 
 
 def alu(opcode, a, b):
@@ -118,21 +114,15 @@ class OverlayConfig:
     engine.
 
     ``engine`` picks how a chunk of cycles executes — never *what* it
-    computes (all three engines are bit-identical, asserted in tests):
+    computes (the two engines are bit-identical, asserted in tests):
 
       * ``"jnp"`` (default) — the pure-jnp reference path: one ``lax.scan``
         of the cycle body per chunk;
       * ``"select"`` — the jnp cycle body with the scheduler pick routed
         through the fused Pallas kernels in :mod:`repro.kernels.lod` (one
-        VMEM round-trip per pick), for policies that support it;
-      * ``"megakernel"`` — the whole ``check_every``-cycle chunk fused into
-        ONE ``pallas_call`` (:mod:`repro.kernels.megakernel`): select +
-        Hoplite route + fused eject + termination counter with state
-        carried across cycles in kernel refs. Sharded engines raise
-        ``ValueError`` for it whenever a mesh axis is >1 (collectives
-        cannot live inside a kernel — see docs/megakernel.md).
+        VMEM round-trip per pick), for policies that support it.
 
-    On non-TPU backends the Pallas engines run in interpret mode.
+    On non-TPU backends the Pallas kernels run in interpret mode.
 
     ``eject_policy`` picks the NoC's single-port eject arbitration:
     ``"n_first"`` (Hoplite's N-beats-W default) or ``"priority"`` (the
@@ -168,7 +158,7 @@ class OverlayConfig:
     check_every: int | None = None   # cycles per termination check; None=auto
     eject_policy: str = "n_first"    # NoC eject arbitration (see noc.py)
     placement: Any = None            # PlacementSpec | strategy name | None
-    engine: str = "jnp"              # "jnp" | "select" | "megakernel"
+    engine: str = "jnp"              # "jnp" | "select"
     telemetry: Any = None            # TelemetrySpec | None = tracing off
 
     def __post_init__(self):
@@ -219,8 +209,6 @@ def resolve_check_every(cfg: OverlayConfig, nx: int, ny: int, L: int, *,
         graph size (~1.5x on an 8-device CPU mesh): always 32;
       * single-device TPU — the compiled chunk body is cheap relative to the
         host-visible while_loop predicate sync: at least 16;
-      * ``engine="megakernel"`` — one kernel dispatch per chunk, so the
-        launch amortizes with depth regardless of graph size: always 32;
       * ``engine="select"`` — one Pallas select dispatch per *cycle*; a
         deeper chunk keeps more of them inside one while-loop iteration:
         at least 16.
@@ -230,8 +218,6 @@ def resolve_check_every(cfg: OverlayConfig, nx: int, ny: int, L: int, *,
     if cfg.check_every is not None:
         return cfg.check_every
     if num_devices > 1:
-        return 32
-    if cfg.engine == "megakernel":
         return 32
     slots = nx * ny * L
     base = 8 if slots <= 4_096 else (16 if slots <= 65_536 else 32)
@@ -276,7 +262,7 @@ def _resolve(cfg: OverlayConfig, scheduler: schedulers.Scheduler | None):
 # counter's increment must be zero once ``done`` holds — that is what lets
 # the guard-free chunk engines over-simulate past completion without drift.
 # Register new counters here (telemetry, future schedulers); the chunk
-# repair, init_state, the megakernel repair and the sharded engines all
+# repair, init_state and the sharded engines all
 # iterate the registry, so no repair code needs editing.
 # ---------------------------------------------------------------------------
 
@@ -657,29 +643,6 @@ def make_chunk_fn(cycle_fn, check_every: int,
     return chunk
 
 
-def make_engine_chunk_fn(g: DeviceGraph, cfg: OverlayConfig, check_every: int,
-                         *, scheduler: schedulers.Scheduler | None = None,
-                         batched: bool = False,
-                         all_reduce: Callable[[Any], Any] = lambda x: x,
-                         cycle_fn=None):
-    """Chunk step for ``cfg.engine`` — the dispatch point every execution
-    engine routes through. ``"megakernel"`` builds the fused single-
-    ``pallas_call`` chunk (:mod:`repro.kernels.megakernel`); ``"jnp"`` and
-    ``"select"`` scan ``cycle_fn`` (built here when not supplied). With
-    ``batched=True`` the returned chunk operates on a stacked config axis
-    (the jnp path vmaps; the megakernel vmaps its in-kernel cycle body)."""
-    if cfg.engine == "megakernel":
-        from ..kernels import megakernel  # lazy: kernels layer is optional
-
-        return megakernel.make_mega_chunk_fn(
-            g, cfg, check_every, scheduler=scheduler, batched=batched,
-            all_reduce=all_reduce)
-    if cycle_fn is None:
-        cycle_fn = make_cycle_fn(g, cfg, scheduler=scheduler)
-    chunk = make_chunk_fn(cycle_fn, check_every, all_reduce)
-    return jax.vmap(chunk) if batched else chunk
-
-
 @dataclasses.dataclass
 class SimResult:
     cycles: int
@@ -706,13 +669,11 @@ def _run_jit(g: dict, cfg: OverlayConfig, nx: int, ny: int):
     def cond(s):
         return (~s["done"]) & (s["cycle"] < cfg.max_cycles)
 
-    if K > 1 or cfg.engine == "megakernel":
+    if K > 1:
         # Chunked phase: K back-to-back cycles per termination check, entered
         # only while a full chunk fits the budget (so no freeze guard is
         # needed inside); the per-cycle loop below finishes the < K tail.
-        # The megakernel engine chunks even at K=1 so a check_every=1 run
-        # still exercises (and is bit-pinned against) the fused kernel.
-        chunk = make_engine_chunk_fn(g, cfg, K, cycle_fn=cycle_fn)
+        chunk = make_chunk_fn(cycle_fn, K)
         state = jax.lax.while_loop(
             lambda s: (~s["done"]) & (s["cycle"] + K <= cfg.max_cycles),
             chunk, state)
@@ -838,14 +799,13 @@ def _run_batch_jit(g: dict, cfg: OverlayConfig, names: tuple[str, ...],
         # are exactly what a solo run with the same config would report.
         return jax.tree.map(freeze, s, new)
 
-    if K > 1 or cfg.engine == "megakernel":
+    if K > 1:
         # Chunked phase, vmapped whole: guard-free K-cycle chunks run while
         # every still-running element has a full chunk of budget left
         # (completed elements are fixed points and get their cycle counter
         # repaired from their own done trace — see make_chunk_fn); the
         # per-cycle freeze body then finishes the heterogeneous tail.
-        vchunk = make_engine_chunk_fn(g, cfg, K, scheduler=sched,
-                                      batched=True, cycle_fn=cycle_fn)
+        vchunk = jax.vmap(make_chunk_fn(cycle_fn, K))
 
         def chunk_cond(s):
             running = (~s["done"]) & (s["cycle"] < max_cycs)
@@ -891,7 +851,7 @@ def _simulate_batch(gm: GraphMemory | DataflowGraph,
     if len(engines) != 1:
         raise ValueError(
             f"simulate_batch needs a uniform engine "
-            f"('jnp' | 'select' | 'megakernel'), got {engines}")
+            f"('jnp' | 'select'), got {engines}")
     placements = {c.placement for c in cfgs}
     if len(placements) != 1:
         raise ValueError(

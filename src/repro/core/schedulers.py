@@ -20,9 +20,7 @@ The cycle kernel drives one fused entry point per cycle,
 composes ``select`` + ``commit`` so policies only implement the hooks above,
 while ``ooo``/``scan``/``lru_flat`` override it to route the pick + RDY
 clear through the fused Pallas kernels (:mod:`repro.kernels.lod`) when
-``OverlayConfig(engine="select")`` (the deprecated ``use_pallas=True``
-spelling shims to it); ``engine="megakernel"`` runs the *whole* chunk —
-this protocol included — inside one Pallas kernel (see docs/megakernel.md).
+``OverlayConfig(engine="select")``.
 
 All hooks are pure jnp functions of [nx, ny, ...] arrays, so every policy
 works unchanged under ``jax.jit``, ``shard_map`` (state is local to a PE row)

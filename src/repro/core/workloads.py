@@ -249,12 +249,6 @@ def fig1_full(target_nodes: int = 470_000, seed: int = 0, *,
     return cached_graph(name, builder, cache_dir=cache_dir)
 
 
-#: fig1-family graphs the BENCH ``megakernel`` section (and the tier-1
-#: ``python -m repro.kernels --smoke`` gate) simulate — CI pre-warms these so
-#: neither ever pays the Python elimination loop.
-MEGAKERNEL_BENCH_GRAPHS = ("arrow_b4_s10_w8_seed3", "arrow_b8_s10_w8_seed3")
-
-
 def service_stream(n_queries: int = 32, distinct: int = 8,
                    seed: int = 0) -> list:
     """Deterministic replayed graph stream for the placement service.
@@ -300,8 +294,7 @@ def warm_cache(names: list[str] | None = None) -> dict[str, int]:
     minutes-long Python elimination loops into millisecond npz loads, and a
     cold cache is populated once per workload-code change (the cache key is
     a hash of this file). Known names: ``fig1_full``, the benchmark sweep's
-    ``arrow_b{blocks}_s{size}_w{border}_seed{seed}`` family, and the
-    ``megakernel_bench`` alias (expands to :data:`MEGAKERNEL_BENCH_GRAPHS`).
+    ``arrow_b{blocks}_s{size}_w{border}_seed{seed}`` family.
     Returns ``{name: num_nodes}`` for the log.
     """
     names = names or ["fig1_full"]
@@ -309,9 +302,6 @@ def warm_cache(names: list[str] | None = None) -> dict[str, int]:
     for name in names:
         if name == "fig1_full":
             built[name] = fig1_full().num_nodes
-            continue
-        if name == "megakernel_bench":
-            built.update(warm_cache(list(MEGAKERNEL_BENCH_GRAPHS)))
             continue
         if name.startswith("arrow_"):
             parts = dict(

@@ -11,7 +11,7 @@ survive ``PYTHONHASHSEED`` and can name on-disk cache entries.
 Execution-only knobs are deliberately EXCLUDED from the key: ``engine`` and
 ``check_every`` pick *how* a chunk of cycles executes, never what it
 computes (all engines are bit-identical, the repo-wide contract), so a
-result simulated under ``engine="megakernel"`` legitimately serves a later
+result simulated under ``engine="select"`` legitimately serves a later
 ``engine="jnp"`` query for the same model.
 """
 from __future__ import annotations

@@ -13,8 +13,8 @@ without perturbing the model. Opt in via::
 
 Traces accumulate as integer tensors *inside* the jitted cycle loop
 (:mod:`.trace`), ride the state pytree through all four engines — solo,
-batched, sharded, batched-sharded — and through the chunk repair and the
-fused megakernel, and are bit-identical across every engine and
+batched, sharded, batched-sharded — and through the chunk repair, and
+are bit-identical across every engine and
 ``check_every``. ``telemetry=None`` (the default) compiles to exactly the
 untraced program. ``python -m repro.telemetry`` runs a cached fig1 workload
 and renders an ASCII heatmap + a Perfetto trace artifact.

@@ -70,7 +70,7 @@ def fig1(out_dir: str) -> None:
     from repro.core.partition import build_graph_memory
     from repro.telemetry import TelemetrySpec
 
-    name = wl.MEGAKERNEL_BENCH_GRAPHS[0]
+    name = "arrow_b4_s10_w8_seed3"
     g = wl.cached_graph(name, lambda: wl.arrow_lu_graph(4, 10, 8, seed=3))
     spec = TelemetrySpec()
     for sched in ("ooo", "inorder"):

@@ -6,9 +6,7 @@ by ``overlay.make_cycle_fn`` with signals the model already computed. Both
 are pure jnp functions of [nx, ny]-local arrays, so they work unchanged
 under ``jax.vmap`` (the batched sweep engine), ``shard_map`` (leaves keep
 the grid dims as their LAST TWO axes — one tiled all_gather per mesh axis
-reassembles the global trace, see ``distributed._gather_telem``) and inside
-the megakernel's ``pallas_call`` (leaves flatten to kernel refs like any
-other state leaf).
+reassembles the global trace, see ``distributed._gather_telem``).
 
 Bit-determinism contract: every increment is integer, PE-local, and —
 except ``stall_no_ready``, repaired by ``overlay.repair_telemetry`` — zero

@@ -1,6 +1,7 @@
 """Chunked stepping engine: bit-exactness against the ``check_every=1``
-per-cycle reference for every registered policy, under all three execution
-engines (solo, batched sweep, sharded), plus the fused Pallas select path.
+per-cycle reference for every registered policy, through all three entry
+points (solo, batched sweep, sharded) and both engines (``jnp`` and the
+Pallas ``select`` pick), plus the engine-keyed ``check_every`` autotune.
 
 The chunked engine's correctness argument is that a completed overlay is a
 fixed point of the cycle function and the exact completion cycle is repaired
@@ -11,14 +12,17 @@ length), heterogeneous cycle budgets, and the cross-shard reduction path.
 import os
 import subprocess
 import sys
+import warnings
 
 import numpy as np
 import pytest
 
+import repro
 from repro.core import schedulers
 from repro.core import workloads as wl
 from repro.core.graph import reference_evaluate
-from repro.core.overlay import OverlayConfig, simulate, simulate_batch
+from repro.core.overlay import (ENGINES, OverlayConfig, resolve_check_every,
+                                simulate, simulate_batch)
 from repro.core.partition import build_graph_memory
 
 ALL_POLICIES = sorted(schedulers.REGISTRY)
@@ -162,31 +166,179 @@ def test_simulate_batch_sharded_matches_serial():
         np.testing.assert_array_equal(rb.values, rs.values)
 
 
-@pytest.mark.parametrize("sched", ["ooo", "scan", "lru_flat"])
-def test_select_engine_bit_identical(sched):
-    # interpret=True on CPU: same fused kernels the TPU path compiles
-    g_small = wl.layered_dag(4, 6, seed=3)
-    gm_small = build_graph_memory(
-        g_small, 2, 2,
+# ---------------------------------------------------------------------------
+# engine="select": the scheduler pick through the Pallas LOD kernels
+# (interpret mode on CPU, the same kernels the TPU path compiles), bit-exact
+# against the check_every=1 jnp reference at every chunk depth and entry point.
+# ---------------------------------------------------------------------------
+
+SELECT_CHECK_EVERYS = (1, 8, 32)
+#: Policies whose ``step(use_pallas=True)`` routes the pick through a kernel.
+KERNEL_POLICIES = {"ooo", "scan", "lru_flat"}
+
+
+def _dag_gm(sched):
+    """``layered_dag(4, 6)`` on 2x2: L = 32 slots, one RDY word per PE."""
+    return build_graph_memory(
+        wl.layered_dag(4, 6, seed=3), 2, 2,
         criticality_order=schedulers.get(sched).wants_criticality_order)
-    ref = simulate(gm_small, OverlayConfig(scheduler=sched, check_every=1))
-    r = simulate(gm_small, OverlayConfig(scheduler=sched, check_every=1,
-                                         engine="select"))
+
+
+@pytest.fixture(scope="module")
+def dag_reference_runs():
+    """check_every=1 jnp reference per policy on the small layered DAG."""
+    out = {}
+    for sched in ALL_POLICIES:
+        out[sched] = repro.run(_dag_gm(sched), OverlayConfig(
+            scheduler=sched, max_cycles=100_000, check_every=1))
+        assert out[sched].done
+    return out
+
+
+@pytest.mark.parametrize("check_every", SELECT_CHECK_EVERYS)
+@pytest.mark.parametrize("sched", ALL_POLICIES)
+def test_select_engine_bit_identical(sched, check_every, dag_reference_runs):
+    r = repro.run(_dag_gm(sched), OverlayConfig(
+        scheduler=sched, max_cycles=100_000, check_every=check_every,
+        engine="select"))
+    ref = dag_reference_runs[sched]
+    assert _stats(r) == _stats(ref), (sched, check_every)
+    np.testing.assert_array_equal(r.values, ref.values)
+
+
+@pytest.mark.parametrize("sched", ALL_POLICIES)
+def test_select_engine_multiword_bit_identical(sched, reference_runs):
+    # L = 512 slots on 2x2 is 16 RDY words per PE: the LOD kernel's
+    # multi-word pick, at the chunk depth the chip runs.
+    gm = _gm(sched)
+    assert gm.lmax // 32 == 16
+    r = repro.run(gm, OverlayConfig(scheduler=sched, max_cycles=500_000,
+                                    check_every=32, engine="select"))
+    ref = reference_runs[sched]
     assert _stats(r) == _stats(ref), sched
     np.testing.assert_array_equal(r.values, ref.values)
 
 
-def test_select_engine_batched_bit_identical():
-    # the Pallas kernels must also batch correctly under the vmapped engine
-    g = wl.layered_dag(4, 6, seed=3)
-    gm = build_graph_memory(g, 2, 2, criticality_order=True)
-    cfgs = [OverlayConfig(scheduler=p, engine="select", max_cycles=100_000)
+@pytest.mark.parametrize("check_every", SELECT_CHECK_EVERYS)
+def test_select_engine_batched_bit_identical(check_every):
+    # The kernels batch under the vmapped engine, and a member whose own
+    # budget runs out mid-chunk freezes there, as its solo run does.
+    gm = build_graph_memory(wl.layered_dag(4, 6, seed=3), 2, 2,
+                            criticality_order=True)
+    cfgs = [OverlayConfig(scheduler=p, max_cycles=100_000,
+                          check_every=check_every, engine="select")
+            for p in ALL_POLICIES]
+    cfgs.append(OverlayConfig(scheduler="scan", max_cycles=20,
+                              check_every=check_every, engine="select"))
+    for cfg, rb in zip(cfgs, repro.run(gm, batch=cfgs)):
+        rs = repro.run(gm, OverlayConfig(
+            scheduler=cfg.scheduler, max_cycles=cfg.max_cycles, check_every=1))
+        assert _stats(rb) == _stats(rs), (cfg.scheduler, check_every)
+        np.testing.assert_array_equal(rb.values, rs.values)
+
+
+def test_select_engine_sharded_bit_identical():
+    import jax
+
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    gm = build_graph_memory(wl.layered_dag(4, 6, seed=3), 2, 2,
+                            criticality_order=True)
+    ref = repro.run(gm, OverlayConfig(scheduler="ooo", max_cycles=100_000,
+                                      check_every=1))
+    r = repro.run(gm, OverlayConfig(scheduler="ooo", max_cycles=100_000,
+                                    check_every=8, engine="select"),
+                  mesh=mesh)
+    assert _stats(r) == _stats(ref)
+    np.testing.assert_array_equal(r.values, ref.values)
+    cfgs = [OverlayConfig(scheduler=p, max_cycles=100_000, engine="select")
             for p in ("ooo", "scan")]
-    for cfg, rb in zip(cfgs, simulate_batch(gm, cfgs)):
-        rs = simulate(gm, OverlayConfig(scheduler=cfg.scheduler,
-                                        max_cycles=100_000, check_every=1))
+    for cfg, rb in zip(cfgs, repro.run(gm, mesh=mesh, batch=cfgs)):
+        rs = repro.run(gm, OverlayConfig(scheduler=cfg.scheduler,
+                                         max_cycles=100_000, check_every=1))
         assert _stats(rb) == _stats(rs), cfg.scheduler
         np.testing.assert_array_equal(rb.values, rs.values)
+
+
+def _primitives(jaxpr) -> list:
+    """Every primitive of ``jaxpr``, nested jaxprs (scan, cond, jit,
+    custom_vjp bodies) included."""
+    from jax.extend import core
+
+    out = []
+    for eqn in jaxpr.eqns:
+        out.append(eqn.primitive.name)
+        todo = list(eqn.params.values())
+        while todo:
+            v = todo.pop()
+            if isinstance(v, core.ClosedJaxpr):
+                out += _primitives(v.jaxpr)
+            elif isinstance(v, core.Jaxpr):
+                out += _primitives(v)
+            elif isinstance(v, (tuple, list)):
+                todo += v
+    return out
+
+
+def _chunk_primitives(sched, engine):
+    import jax
+
+    from repro.core.overlay import (device_graph, init_state, make_chunk_fn,
+                                    make_cycle_fn)
+
+    cfg = OverlayConfig(scheduler=sched, engine=engine)
+    g = device_graph(_dag_gm(sched))
+    chunk = make_chunk_fn(make_cycle_fn(g, cfg), 8)
+    return _primitives(jax.make_jaxpr(chunk)(init_state(g, cfg)).jaxpr)
+
+
+@pytest.mark.parametrize("sched", ALL_POLICIES)
+def test_select_chunk_launches_kernel(sched):
+    # A select chunk is a scan whose cycle body calls the LOD kernel for
+    # exactly the policies that route their pick through it: what the
+    # select cell measures on the chip is the kernel.
+    prims = _chunk_primitives(sched, "select")
+    assert "scan" in prims
+    assert ("pallas_call" in prims) == (sched in KERNEL_POLICIES), sched
+
+
+def test_jnp_chunk_has_no_pallas_call():
+    prims = _chunk_primitives("ooo", "jnp")
+    assert "scan" in prims
+    assert "pallas_call" not in prims
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_resolve_check_every_keys_on_engine(backend):
+    # jnp autotunes from the slot count (8 / 16 / 32); select takes at
+    # least 16 (one kernel dispatch per cycle), and so does any engine on
+    # a TPU. At paper shapes (16x16, L = 1,856) both engines run 32.
+    kw = dict(backend=backend, num_devices=1)
+    small = {"cpu": {"jnp": 8, "select": 16},
+             "tpu": {"jnp": 16, "select": 16}}[backend]
+    for engine in ENGINES:
+        cfg = OverlayConfig(engine=engine)
+        assert resolve_check_every(cfg, 2, 2, 32, **kw) == small[engine]
+        assert resolve_check_every(cfg, 16, 16, 1856, **kw) == 32
+        # an explicit check_every always wins over the engine keying
+        assert resolve_check_every(OverlayConfig(engine=engine, check_every=4),
+                                   2, 2, 32, **kw) == 4
+        # a multi-device mesh amortizes its per-chunk collectives: 32
+        assert resolve_check_every(cfg, 2, 2, 32, backend=backend,
+                                   num_devices=8) == 32
+
+
+def test_use_pallas_removed():
+    # engine= is the only spelling, and only the two engines exist
+    with pytest.raises(TypeError):
+        OverlayConfig(use_pallas=True)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        OverlayConfig(engine="select")
+    assert not caught
+    assert ENGINES == ("jnp", "select")
+    for gone in ("megakernel", "turbo"):
+        with pytest.raises(ValueError, match="engine"):
+            OverlayConfig(engine=gone)
 
 
 def test_simulate_batch_rejects_mixed_engine():

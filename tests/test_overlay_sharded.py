@@ -1,6 +1,5 @@
 """shard_map overlay: cycle-exact equivalence with the single-device sim,
-and the megakernel refusing a multi-shard mesh (subprocesses with fake host
-devices)."""
+for the jnp and the select engine (subprocesses with fake host devices)."""
 import os
 import subprocess
 import sys
@@ -29,27 +28,32 @@ print("SHARDED_EXACT_OK")
 """
 
 
-MEGAKERNEL_SCRIPT = r"""
+SELECT_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys; sys.path.insert(0, "src")
+import numpy as np
 import repro
 from repro.core import workloads as wl
 from repro.core.overlay import OverlayConfig
 from repro.core.partition import build_graph_memory
 from repro.launch.mesh import make_local_mesh
 mesh = make_local_mesh(2, 2)
-gm = build_graph_memory(wl.layered_dag(4, 6, seed=3), 2, 2)
-cfg = OverlayConfig(engine="megakernel")
-for call in (lambda: repro.run(gm, cfg, mesh=mesh),
-             lambda: repro.run(gm, mesh=mesh, batch=[cfg, cfg])):
-    try:
-        call()
-    except ValueError as e:
-        assert "multi-shard mesh" in str(e), e
-    else:
-        raise AssertionError("engine='megakernel' ran on a 2x2 mesh")
-print("MEGAKERNEL_MESH_RAISES_OK")
+gm = build_graph_memory(wl.layered_dag(4, 6, seed=3), 2, 2,
+                        criticality_order=True)
+def same(r, ref, ctx):
+    assert r.done and (r.cycles, r.deflections, r.busy_cycles,
+                       r.delivered) == (ref.cycles, ref.deflections,
+                                        ref.busy_cycles, ref.delivered), ctx
+    np.testing.assert_array_equal(r.values, ref.values)
+ref = {p: repro.run(gm, OverlayConfig(scheduler=p, check_every=1))
+       for p in ("ooo", "scan")}
+same(repro.run(gm, OverlayConfig(engine="select"), mesh=mesh), ref["ooo"],
+     "solo")
+cfgs = [OverlayConfig(scheduler=p, engine="select") for p in ("ooo", "scan")]
+for cfg, r in zip(cfgs, repro.run(gm, mesh=mesh, batch=cfgs)):
+    same(r, ref[cfg.scheduler], cfg.scheduler)
+print("SELECT_MESH_OK")
 """
 
 
@@ -67,6 +71,8 @@ def test_sharded_overlay_cycle_exact():
     assert "SHARDED_EXACT_OK" in out.stdout, out.stderr[-2000:]
 
 
-def test_megakernel_on_multi_shard_mesh_raises():
-    out = _run_script(MEGAKERNEL_SCRIPT, 120)
-    assert "MEGAKERNEL_MESH_RAISES_OK" in out.stdout, out.stderr[-2000:]
+def test_select_engine_on_multi_shard_mesh():
+    # The select kernel runs shard-local, with the torus exchange and the
+    # per-chunk reductions outside it: a 2x2 mesh equals one device.
+    out = _run_script(SELECT_SCRIPT, 120)
+    assert "SELECT_MESH_OK" in out.stdout, out.stderr[-2000:]

@@ -74,7 +74,6 @@ def test_query_key_ignores_execution_only_knobs():
     # produces — configs differing only there must share one cache entry.
     base = query_key(G, NX, NY, CFG, "cycles")
     for variant in (dataclasses.replace(CFG, engine="select"),
-                    dataclasses.replace(CFG, engine="megakernel"),
                     dataclasses.replace(CFG, check_every=1)):
         assert query_key(G, NX, NY, variant, "cycles") == base
 

@@ -26,7 +26,6 @@ from repro.telemetry import TelemetrySpec
 from repro.telemetry.perfetto import track_count
 
 ALL_POLICIES = sorted(schedulers.REGISTRY)
-ENGINES = ("jnp", "select", "megakernel")
 SPEC = TelemetrySpec(buckets=16, bucket_cycles=8)
 
 
@@ -60,16 +59,13 @@ def reference_runs():
 # ---------------------------------------------------------------------------
 
 # Engine x chunk-depth sampling: the jnp reference path runs the full policy
-# matrix; the Pallas engines run representative policies here because their
-# full policy x chunk-depth off-matrices are already pinned bit-for-bit by
-# tests/test_chunked.py and tests/test_megakernel.py against the same
-# check_every=1 reference these fixtures rebuild.
+# matrix; the select engine runs representative policies here because its
+# full policy x chunk-depth off-matrix is already pinned bit-for-bit by
+# tests/test_chunked.py against the same check_every=1 reference.
 OFF_MATRIX = [
     ("jnp", 1, ALL_POLICIES), ("jnp", 8, ALL_POLICIES),
     ("jnp", 32, ALL_POLICIES),
     ("select", 8, ("ooo", "scan")),
-    ("megakernel", 8, ("ooo", "inorder")),
-    ("megakernel", 32, ("lru_flat",)),
 ]
 
 
@@ -107,8 +103,8 @@ ON_MATRIX = [
     ("jnp", 32, ALL_POLICIES),
     ("select", 8, ("ooo", "lru_flat")),
     ("select", 32, ("scan",)),
-    ("megakernel", 8, ("ooo", "inorder")),
-    ("megakernel", 32, ("lru_flat",)),
+    ("select", 1, ALL_POLICIES),
+    ("select", 32, ("ooo", "lru_flat")),
 ]
 
 

@@ -23,7 +23,7 @@ from repro.core import overlay
 from repro.core import workloads as wl
 from repro.core.overlay import OverlayConfig
 from repro.core.partition import build_graph_memory
-from repro.kernels import lod, megakernel, ops
+from repro.kernels import lod, ops
 
 #: One block of 256 PE rows at the paper-scale width: L = 1856 slots per PE
 #: (fig1 arrow-LU, 470,772 nodes on 16x16) is 58 32-bit RDY words.
@@ -86,8 +86,8 @@ def _compile_chunk(graph, sharding, engine):
     L = graph["opcode"].shape[2]
     K = overlay.resolve_check_every(cfg, GRID, GRID, L, backend="tpu")
     state = jax.eval_shape(lambda g: overlay.init_state(g, cfg), graph)
-    chunk = jax.jit(
-        lambda g, s: overlay.make_engine_chunk_fn(g, cfg, K)(s))
+    chunk = jax.jit(lambda g, s: overlay.make_chunk_fn(
+        overlay.make_cycle_fn(g, cfg), K)(s))
     return chunk.lower(_shapes(graph, sharding),
                        _shapes(state, sharding)).compile()
 
@@ -155,6 +155,17 @@ def _reachable(comps: dict, roots) -> set:
     return seen
 
 
+def _loop_scatters(hlo: str) -> list:
+    """The ``scatter`` instructions any while loop of a compiled program
+    runs, directly or in a computation it calls."""
+    comps = _computations(hlo)
+    bodies = re.findall(r" while\(.*body=%([\w.-]+)", hlo)
+    assert bodies
+    return [line.strip()[:120]
+            for name in _reachable(comps, bodies)
+            for line in comps[name] if re.search(r" scatter\(", line)]
+
+
 @pytest.mark.parametrize("engine", ["jnp", "select"])
 def test_chunk_loop_has_no_scatter(one_chip, fig1_graph, monkeypatch,
                                    engine):
@@ -162,20 +173,15 @@ def test_chunk_loop_has_no_scatter(one_chip, fig1_graph, monkeypatch,
     # one-hot dense pass: a point scatter costs per index on a TPU, and
     # six of them took most of a cycle's device time.
     monkeypatch.setattr(ops, "_interpret", lambda: False)
-    text = _compile_chunk(fig1_graph, one_chip, engine).as_text()
-    comps = _computations(text)
-    bodies = re.findall(r" while\(.*body=%([\w.-]+)", text)
-    assert bodies
-    scatters = [line.strip()[:120]
-                for name in _reachable(comps, bodies)
-                for line in comps[name] if re.search(r" scatter\(", line)]
-    assert not scatters
+    assert not _loop_scatters(
+        _compile_chunk(fig1_graph, one_chip, engine).as_text())
 
 
-def test_megakernel_is_refused(one_chip, fig1_graph, monkeypatch):
-    # The per-PE [nx, ny, L] gathers of the cycle body, traced into one
-    # kernel, are more than the TPU kernel compiler lowers. Once this
-    # passes no more, the megakernel compiles: measure it on the chip.
-    monkeypatch.setattr(megakernel, "_interpret", lambda: False)
-    with pytest.raises(NotImplementedError, match="Only 2D gather"):
-        _compile_chunk(fig1_graph, one_chip, "megakernel")
+def test_batched_chunk_program_compiles(one_chip, fig1_graph):
+    # The batched jnp program (the sweep cell's) at fig1 shapes, B = 4: it
+    # compiles, and no loop of it holds a point scatter either.
+    batch = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    compiled = overlay._run_batch_jit.lower(
+        _shapes(fig1_graph, one_chip), OverlayConfig(), ("ooo",), batch,
+        batch, batch, GRID, GRID).compile()
+    assert not _loop_scatters(compiled.as_text())
