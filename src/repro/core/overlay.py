@@ -71,7 +71,9 @@ from .. import (
     SPAN_UNPACK,
 )
 from . import bitvec, noc, schedulers
-from .graph import DIV_EPS, OP_ADD, OP_DIV, OP_MUL, OP_SUB, DataflowGraph
+from .graph import (
+    DIV_EPS, OP_ADD, OP_DIV, OP_MUL, OP_NAMES, OP_SUB, DataflowGraph,
+)
 from .partition import GraphMemory
 from .schedulers import row_gather as _row_gather
 
@@ -229,23 +231,105 @@ def resolve_check_every(cfg: OverlayConfig, nx: int, ny: int, L: int, *,
     return base
 
 
+#: Width of a node word's opcode field: the bits of the largest opcode.
+OPCODE_BITS = max(OP_NAMES).bit_length()
+
+
 class DeviceGraph(dict):
-    """GraphMemory as jnp arrays reshaped to [nx, ny, ...]."""
+    """GraphMemory as jnp arrays reshaped to [nx, ny, ...].
+
+    ``opcode``, ``fanin``, ``init_value``, ``fo_count`` and ``valid`` are the
+    node records that ``init_state`` and the schedulers' ``init`` read. The
+    cycle body reads the static tables as two packed int32 words instead,
+    one gather per index for all the fields it needs there (the paper's PE
+    likewise reads one node record per access):
+
+      * ``e_word`` [nx, ny, emax], read at the fanout cursor:
+        ``((dst_pe * L + dst_slot) * 2 + last) * 2 + opidx``, with
+        ``dst_pe`` the global PE index and ``last`` set on the final edge of
+        its source node's fanout list. Padded columns are 0. Fits while
+        ``4 * P * L <= 2**31`` (P PEs of L slots).
+      * ``n_word`` [nx, ny, L], read at the ejected and the picked slot:
+        ``(fo_base * 2**OPCODE_BITS + opcode) * 2 + has_fanout``, with
+        ``has_fanout = fo_count > 0``. Fits while
+        ``(emax + 1) * 2**(OPCODE_BITS + 1) <= 2**31``, since a node's
+        ``fo_base`` is at most ``emax``.
+
+    :func:`edge_fields` and :func:`node_fields` decode them.
+    """
+
+
+def check_word_limits(num_pes: int, lmax: int, emax: int) -> None:
+    """Raise ``ValueError`` unless both packed words of a ``num_pes`` x
+    ``lmax``-slot graph memory with ``emax`` edges per PE fit in int32."""
+    if 4 * num_pes * lmax > 2**31:
+        raise ValueError(
+            f"edge word overflows int32: {num_pes} PEs x {lmax} slots = "
+            f"{num_pes * lmax} slots, the limit is 2**29")
+    if (emax + 1) << (OPCODE_BITS + 1) > 2**31:
+        raise ValueError(
+            f"node word overflows int32: emax = {emax} edges per PE, the "
+            f"limit is 2**{30 - OPCODE_BITS} - 1 with {OPCODE_BITS} opcode "
+            f"bits")
+
+
+def edge_word(gm: GraphMemory) -> np.ndarray:
+    """[P, emax] int32 edge words of ``gm`` (see :class:`DeviceGraph`).
+
+    In place on one int32 copy, and ``last`` by one store at the flat
+    column of each fanout list's final edge: this runs on the host for
+    every query."""
+    last = np.zeros(gm.e_dst_pe.shape, bool)
+    end = gm.fo_base + gm.fo_count - 1 \
+        + (np.arange(gm.num_pes) * gm.emax)[:, None]
+    last.ravel()[end[gm.fo_count > 0]] = True
+    word = gm.e_dst_pe.astype(np.int32)
+    word *= gm.lmax
+    word += gm.e_dst_slot
+    word <<= 1
+    word += last
+    word <<= 1
+    word += gm.e_dst_opidx
+    return word
+
+
+def node_word(gm: GraphMemory) -> np.ndarray:
+    """[P, lmax] int32 node words of ``gm`` (see :class:`DeviceGraph`)."""
+    word = gm.fo_base.astype(np.int32)
+    word <<= OPCODE_BITS
+    word += gm.opcode
+    word <<= 1
+    word += gm.fo_count > 0
+    return word
+
+
+def edge_fields(word, L: int) -> dict:
+    """Decode edge words (numpy or jnp) of a graph memory with ``L`` slots
+    per PE: ``dst_pe``, ``dst_slot``, ``last`` (bool) and ``opidx``."""
+    return dict(dst_pe=(word >> 2) // L, dst_slot=(word >> 2) % L,
+                last=(word & 2) != 0, opidx=word & 1)
+
+
+def node_fields(word) -> dict:
+    """Decode node words (numpy or jnp): ``fo_base``, ``opcode`` and
+    ``has_fanout`` (bool)."""
+    return dict(fo_base=word >> (OPCODE_BITS + 1),
+                opcode=(word >> 1) & ((1 << OPCODE_BITS) - 1),
+                has_fanout=(word & 1) != 0)
 
 
 def device_graph(gm: GraphMemory) -> DeviceGraph:
     nx, ny = gm.nx, gm.ny
+    check_word_limits(gm.num_pes, gm.lmax, gm.emax)
     r3 = lambda a: jnp.asarray(a).reshape(nx, ny, -1)
     return DeviceGraph(
         opcode=r3(gm.opcode).astype(jnp.int32),
         fanin=r3(gm.fanin).astype(jnp.int32),
         init_value=r3(gm.init_value),
-        fo_base=r3(gm.fo_base).astype(jnp.int32),
         fo_count=r3(gm.fo_count).astype(jnp.int32),
         valid=r3(gm.valid),
-        e_dst_pe=r3(gm.e_dst_pe).astype(jnp.int32),
-        e_dst_slot=r3(gm.e_dst_slot).astype(jnp.int32),
-        e_dst_opidx=r3(gm.e_dst_opidx).astype(jnp.int32),
+        n_word=r3(node_word(gm)),
+        e_word=r3(edge_word(gm)),
     )
 
 
@@ -318,7 +402,6 @@ def init_state(g: DeviceGraph, cfg: OverlayConfig,
         sched=sched.init(g, cfg),
         active=jnp.full((nx, ny), -1, jnp.int32),
         cursor=jnp.zeros((nx, ny), jnp.int32),
-        cursor_end=jnp.zeros((nx, ny), jnp.int32),
         sel_lat=jnp.int32(lat),
         sel_wait=jnp.full((nx, ny), lat - 1, jnp.int32),
         link_e=noc.empty_packets(nx, ny),
@@ -396,15 +479,18 @@ def make_cycle_fn(
     def cycle(s):
         with jax.named_scope(SCOPE_INJECT):
             # ---- 1. offer injection packet from the active node's fanout
-            # cursor
-            inj_valid = (s["active"] >= 0) & (s["cursor"] < s["cursor_end"])
-            dst_pe = _row_gather(g["e_dst_pe"], s["cursor"])
+            # cursor. One gather of the edge word (see DeviceGraph) gives
+            # the packet's destination PE, slot and operand side, and
+            # whether it is the node's last edge. A node with no fanout is
+            # never enqueued, so an active node always has an edge to send.
+            inj_valid = s["active"] >= 0
+            edge = edge_fields(_row_gather(g["e_word"], s["cursor"]), L)
             inject = dict(
                 valid=inj_valid,
-                dst_x=dst_pe // ny_i32,
-                dst_y=dst_pe % ny_i32,
-                dst_slot=_row_gather(g["e_dst_slot"], s["cursor"]),
-                opidx=_row_gather(g["e_dst_opidx"], s["cursor"]),
+                dst_x=edge["dst_pe"] // ny_i32,
+                dst_y=edge["dst_pe"] % ny_i32,
+                dst_slot=edge["dst_slot"],
+                opidx=edge["opidx"],
                 value=_row_gather(s["value"], s["active"]),
             )
 
@@ -417,10 +503,11 @@ def make_cycle_fn(
                 eject_policy=cfg.eject_policy,
             )
 
-            # ---- 3. advance fanout cursor; retire drained nodes
+            # ---- 3. advance fanout cursor; retire drained nodes: a node is
+            # drained the cycle its router accepts the edge word's ``last``
+            # edge
             cursor = s["cursor"] + accepted.astype(jnp.int32)
-            cursor_end = s["cursor_end"]
-            drained = (s["active"] >= 0) & (cursor >= cursor_end)
+            drained = inj_valid & accepted & edge["last"]
             active = jnp.where(drained, -1, s["active"])
             sel_wait = jnp.where(drained, s["sel_lat"] - 1, s["sel_wait"])
 
@@ -462,8 +549,10 @@ def make_cycle_fn(
 
             opnds = jnp.stack([_row_gather(operands, ej_slot[e])
                                for e in range(E)])  # [E,nx,ny,2]
-            opc = jnp.stack([_row_gather(g["opcode"], ej_slot[e])
-                             for e in range(E)])
+            # One node-word gather per port for its opcode and fanout test.
+            node = [node_fields(_row_gather(g["n_word"], ej_slot[e]))
+                    for e in range(E)]
+            opc = jnp.stack([n["opcode"] for n in node])
             fval = alu(opc, opnds[..., 0], opnds[..., 1])
             value, computed = _record_fires(s["value"], s["computed"],
                                             ej_slot, fired, fval)
@@ -471,8 +560,7 @@ def make_cycle_fn(
             # Enqueue fired nodes in eject-port order (per-PE FIFO arrival
             # semantics are exactly the sequential loop's).
             for e in range(E):
-                ready_e = fired[e] & (
-                    _row_gather(g["fo_count"], ej_slot[e]) > 0)
+                ready_e = fired[e] & node[e]["has_fanout"]
                 sched_st = sched.on_ready(sched_st, ej_slot[e], ready_e)
 
             n_delivered = ej_valid.sum().astype(jnp.int32)
@@ -494,10 +582,8 @@ def make_cycle_fn(
             sel = gate & have
 
             active = jnp.where(sel, cand, active)
-            new_base = _row_gather(g["fo_base"], jnp.clip(cand, 0, L - 1))
-            new_cnt = _row_gather(g["fo_count"], jnp.clip(cand, 0, L - 1))
+            new_base = node_fields(_row_gather(g["n_word"], cand))["fo_base"]
             cursor = jnp.where(sel, new_base, cursor)
-            cursor_end = jnp.where(sel, new_base + new_cnt, cursor_end)
 
         with jax.named_scope(SCOPE_TERMINATE):
             # ---- 6. termination + stats. ``remaining`` counts local
@@ -528,7 +614,7 @@ def make_cycle_fn(
                 value=value,
                 remaining=remaining,
                 sched=sched_st,
-                active=active, cursor=cursor, cursor_end=cursor_end,
+                active=active, cursor=cursor,
                 sel_lat=s["sel_lat"], sel_wait=sel_wait,
                 link_e=link_e, link_s=link_s,
                 cycle=s["cycle"] + 1,

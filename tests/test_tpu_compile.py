@@ -177,11 +177,30 @@ def test_chunk_loop_has_no_scatter(one_chip, fig1_graph, monkeypatch,
         _compile_chunk(fig1_graph, one_chip, engine).as_text())
 
 
+def _gathers(hlo: str) -> int:
+    return len(re.findall(r" gather\(", hlo))
+
+
+@pytest.mark.parametrize("engine,gathers", [("jnp", 8), ("select", 7)])
+def test_cycle_body_gathers(one_chip, fig1_graph, monkeypatch, engine,
+                            gathers):
+    # One gather per index of each static table group: the edge word at
+    # the fanout cursor, the node word at the ejected and the picked slot.
+    # The rest read the dynamic state: value at the active node; pending,
+    # computed and operands at the ejected slot; the RDY word of the jnp
+    # pick.
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    text = _compile_chunk(fig1_graph, one_chip, engine).as_text()
+    assert _gathers(text) == gathers
+
+
 def test_batched_chunk_program_compiles(one_chip, fig1_graph):
     # The batched jnp program (the sweep cell's) at fig1 shapes, B = 4: it
-    # compiles, and no loop of it holds a point scatter either.
+    # compiles, no loop of it holds a point scatter either, and it gathers
+    # as the solo jnp body does in each of its two loops.
     batch = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
     compiled = overlay._run_batch_jit.lower(
         _shapes(fig1_graph, one_chip), OverlayConfig(), ("ooo",), batch,
         batch, batch, GRID, GRID).compile()
     assert not _loop_scatters(compiled.as_text())
+    assert _gathers(compiled.as_text()) == 16
